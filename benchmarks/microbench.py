@@ -12,9 +12,19 @@ from typing import Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 Row = Tuple[str, float, str]
+
+
+def _cpu_env(devices: int) -> dict:
+    """Environment of a child process that emulates ``devices`` devices on
+    the host CPU.  ``JAX_PLATFORMS=cpu`` keeps it off any accelerator: a
+    chip belongs to one process, and this parent imports JAX."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    return env
 
 
 def _time(fn, *args, iters=5) -> float:
@@ -66,9 +76,7 @@ for name, overlap in [('hmp', False), ('hmp_ring', True)]:
     jax.block_until_ready(out)
     print(f"{{name}},{{(time.perf_counter()-t0)/{iters}:.9f}}")
 """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _cpu_env(devices)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
@@ -148,9 +156,7 @@ for name, fn in hmp.SCHEDULES.items():
     jax.block_until_ready(out)
     print(f"{name},{(time.perf_counter()-t0)/10*1e6:.1f}")
 """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _cpu_env(4)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
@@ -207,8 +213,8 @@ def execplan_uneven() -> Iterator[Row]:
                f"simulated,{eplan.describe()}" if not padded else
                "simulated,every device runs max(units)")
 
-    # measurement failures propagate: the CI bench-smoke --strict gate's
-    # contract is "fails on exceptions", same as execplan_raggedsp below
+    # measurement failures propagate: the bench runner exits nonzero on
+    # exceptions, same as execplan_raggedsp below
     measured = measure_execplan_layers(eplan, seq)
     for name, sec in measured.items():
         yield (f"micro/execplan_{name}", sec * 1e6,
@@ -364,9 +370,7 @@ for name in ('bucketed', 'bucketed_db'):
         raise RuntimeError(f"{{name}} transport is not bitwise-equal to padded")
 print(f"err_sync,{{err:.3e}}")
 """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _cpu_env(4)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
@@ -421,21 +425,17 @@ def execplan_padshed() -> Iterator[Row]:
     """Pad shedding: the pallas valid-length backend vs the padded-XLA
     oracle on the 3:2:2:1 uneven DistilBert plan.
 
-    Three claims, measured:
+    Two parts:
 
-    1. Per-device dense-block counts of the valid-length GEMMs (the
-       kernel's own live-block counter) equal ``ceil(units[d]/block)`` —
-       each device executes its *assigned* heads/columns, not
-       ``max(units)``.  Block sizes map integrally onto units (one N block
-       per head, 128 columns per MLP block) so counts convert to units
-       exactly.
-    2. The measured waste shed (1 - effective/padded unit-blocks) matches
-       the bookkept ``ExecPlan.padding_waste()``.
-    3. Backend outputs agree with the padded-XLA oracle (atol 1e-4) on the
+    1. Analytic per-device live-block counts of the four per-shard GEMMs
+       at the executor's block sizes (``tiled_gemm.dense_block_count``,
+       the blocks the kernel's grid issues a dot for), and the share of
+       the padded blocks they skip next to ``ExecPlan.padding_waste()``.
+       Kernel time itself comes from a device trace, not from this host.
+    2. Backend outputs agree with the padded-XLA oracle (atol 1e-4) on the
        layer, prefill, and paged-decode paths (4 forced CPU devices), with
-       wall times reported for both (interpret-mode pallas on a CPU host —
-       the FLOPs counters, not the wall clock, are the shedding evidence;
-       the MXU win needs a real TPU lowering).
+       wall times reported for both (interpret-mode pallas on a CPU host,
+       which says nothing about the MXU).
     """
     import dataclasses
 
@@ -443,7 +443,7 @@ def execplan_padshed() -> Iterator[Row]:
     from repro.core import costmodel, planner
     from repro.core.execplan import ExecPlan
     from repro.core.profiler import AnalyticProfiler
-    from repro.kernels import ops
+    from repro.kernels.tiled_gemm import dense_block_count
 
     seq = 128
     cfg = dataclasses.replace(get_config("distilbert"), num_layers=1)
@@ -463,72 +463,27 @@ def execplan_padshed() -> Iterator[Row]:
 
     d, hd = cfg.d_model, cfg.head_dim
     ph, pc = eplan.pad_heads, eplan.pad_columns
-    tile = seq // eplan.num_devices
-    col_block = 128  # divides every planned column count below
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (tile, d))
-    wqkv = jax.random.normal(key, (d, 3 * ph * hd)) * 0.05
-    attn_in = jax.random.normal(key, (tile, ph * hd))
-    wo = jax.random.normal(key, (ph * hd, d)) * 0.05
-    h_in = jax.random.normal(key, (tile, pc))
-    w1 = jax.random.normal(key, (d, pc)) * 0.05
-    w2 = jax.random.normal(key, (pc, d)) * 0.05
 
-    unit = costmodel.gemm_unit_flops(d, hd)
-    eff_units = np.zeros(eplan.num_devices)
-    pad_units = eplan.num_devices * (ph + pc)
+    def blocks(heads, cols):
+        # the four per-shard GEMMs the executor traces on one sequence tile
+        tile = seq // eplan.num_devices
+        return (dense_block_count(tile, 3 * ph * hd, d, valid_n=heads * hd,
+                                  seg_n=ph * hd)
+                + dense_block_count(tile, d, ph * hd, valid_k=heads * hd)
+                + dense_block_count(tile, pc, d, valid_n=cols)
+                + dense_block_count(tile, d, pc, valid_k=cols))
+
+    padded = blocks(ph, pc)
+    live = []
     for dev, (heads, cols) in enumerate(zip(eplan.heads, eplan.columns)):
-        # the same four per-shard GEMMs the executor traces, with this
-        # device's valid counts; counts are measured by the kernel itself
-        _, qkv_cnt = ops.gemm(x, wqkv, backend="pallas",
-                              valid_n=heads * hd, seg_n=ph * hd,
-                              block_m=tile, block_n=hd, block_k=d,
-                              count_blocks=True)
-        _, wo_cnt = ops.gemm(attn_in, wo, backend="pallas",
-                             valid_k=heads * hd, block_m=tile,
-                             block_n=d, block_k=hd, count_blocks=True)
-        _, w1_cnt = ops.gemm(x, w1, backend="pallas", valid_n=cols,
-                             block_m=tile, block_n=col_block, block_k=d,
-                             count_blocks=True)
-        _, w2_cnt = ops.gemm(h_in, w2, backend="pallas", valid_k=cols,
-                             block_m=tile, block_n=d, block_k=col_block,
-                             count_blocks=True)
-        qkv_cnt, wo_cnt = int(qkv_cnt), int(wo_cnt)
-        w1_cnt, w2_cnt = int(w1_cnt), int(w2_cnt)
-        # acceptance gate: live blocks == ceil(units[d]/block), not
-        # max(units) — raise (not assert: this must also gate under -O)
-        expect = {
-            "qkv": (qkv_cnt, 3 * heads),
-            "wo": (wo_cnt, heads),
-            "w1": (w1_cnt, -(-cols // col_block)),
-            "w2": (w2_cnt, -(-cols // col_block)),
-        }
-        for gemm_name, (got, want) in expect.items():
-            if got != want:
-                raise RuntimeError(
-                    f"dev{dev} {gemm_name}: measured {got} live blocks, "
-                    f"expected ceil(units/block)={want}"
-                )
-        heads_meas = qkv_cnt // 3
-        cols_meas = w1_cnt * col_block
-        eff_units[dev] = heads_meas + cols_meas
-        flops_eff = heads_meas * unit["head"] + cols_meas * unit["column"]
-        flops_pad = ph * unit["head"] + pc * unit["column"]
-        yield (f"padshed/blocks_dev{dev}",
-               float(qkv_cnt + wo_cnt + w1_cnt + w2_cnt),
-               f"heads={heads_meas}/{ph},cols={cols_meas}/{pc},"
-               f"eff_flops={flops_eff / flops_pad:.0%}")
-
-    shed = 1.0 - eff_units.sum() / pad_units
-    waste = eplan.padding_waste()
-    if abs(shed - waste) > 0.05 * waste:
-        raise RuntimeError(
-            f"measured waste shed {shed:.1%} drifts >5% from "
-            f"ExecPlan.padding_waste()={waste:.1%}"
-        )
-    yield ("padshed/waste_shed", shed * 100,
-           f"percent,vs ExecPlan.padding_waste={waste:.1%},"
-           f"flops_shed={eplan.flops_shed():.1%}")
+        live.append(blocks(heads, cols))
+        yield (f"padshed/blocks_dev{dev}", float(live[-1]),
+               f"analytic,heads={heads}/{ph},cols={cols}/{pc},"
+               f"padded_blocks={padded}")
+    shed = 1.0 - sum(live) / (padded * eplan.num_devices)
+    yield ("padshed/blocks_shed", shed * 100,
+           f"percent,analytic,vs ExecPlan.padding_waste="
+           f"{eplan.padding_waste():.1%},flops_shed={eplan.flops_shed():.1%}")
 
     # backend outputs vs the padded-XLA oracle on 4 forced CPU devices
     code = rf"""
@@ -574,9 +529,7 @@ for i, path in enumerate(('layer', 'prefill', 'decode_paged')):
         raise RuntimeError(f"{{path}}: pallas vs xla max err {{err:.3e}}")
     print(f"err_{{path}},{{err:.3e}}")
 """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _cpu_env(4)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
@@ -793,9 +746,7 @@ for scheduler in ('wave', 'continuous'):
 assert outs['wave'] == outs['continuous'], 'greedy tokens diverged'
 print(f"page_bytes,{ep.kv_page_bytes(8)},{ep.describe()}")
 """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = _cpu_env(4)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:

@@ -7,7 +7,8 @@ CLI (used by the CI ``bench-smoke`` job):
   --only a,b   run only the named microbench functions (skips paper tables
                and the roofline summary)
   --json PATH  also write {"rows": [row objects], "errors": [strings]}
-  --strict     exit nonzero if any benchmark raised (timings never fail)
+
+Exits nonzero if any benchmark raised (timings never fail).
 """
 from __future__ import annotations
 
@@ -63,8 +64,6 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default="",
                     help="comma-separated microbench function names")
     ap.add_argument("--json", default="", help="write rows as JSON to this path")
-    ap.add_argument("--strict", action="store_true",
-                    help="exit nonzero if any benchmark raised")
     args = ap.parse_args(argv)
 
     from benchmarks import microbench, paper_tables
@@ -87,7 +86,7 @@ def main(argv=None) -> int:
         try:
             for row in fn():
                 _emit(rows, *row)
-        except Exception as e:  # noqa: BLE001 — benches report, not crash
+        except Exception as e:  # noqa: BLE001 — record, run the rest, exit 1
             errors.append(f"{fn.__name__}: {type(e).__name__}: {e}")
             _emit(rows, f"micro/{fn.__name__}", float("nan"),
                   f"error:{type(e).__name__}")
@@ -102,8 +101,7 @@ def main(argv=None) -> int:
         print(f"{len(errors)} benchmark(s) raised:", file=sys.stderr)
         for e in errors:
             print(f"  {e}", file=sys.stderr)
-        if args.strict:
-            return 1
+        return 1
     return 0
 
 

@@ -88,7 +88,8 @@ def hmp_demo():
         "    print(f'  {name:10s} matches reference: max_err={err:.2e}')\n"
     )
     env = dict(os.environ, PYTHONPATH=SRC,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
     print("HMP schedules on a 4-device ring (paper Fig. 5-7):")
     subprocess.run([sys.executable, "-c", code], env=env, check=True)
 
@@ -171,7 +172,8 @@ def raggedsp_serving_demo():
         "      f'stats={eng.stats}')\n"
     )
     env = dict(os.environ, PYTHONPATH=SRC,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
     print("Ragged SP on a bandwidth-heterogeneous cluster (one 100 Mbps hop):")
     subprocess.run([sys.executable, "-c", code], env=env, check=True)
 
@@ -217,7 +219,8 @@ def overlap_transport_demo():
         "      f' ({sched.wire_fraction():.0%} of the padded wire)')\n"
     )
     env = dict(os.environ, PYTHONPATH=SRC,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
     print("Overlap ring transport (padded vs bucketed + double-buffered):")
     subprocess.run([sys.executable, "-c", code], env=env, check=True)
 
@@ -263,7 +266,8 @@ def padshed_backend_demo():
         "      ' pallas sheds', f'{ep.padding_waste():.0%}', 'pad units')\n"
     )
     env = dict(os.environ, PYTHONPATH=SRC,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
     print("Pad-shedding compute backend (xla oracle vs pallas valid-length):")
     subprocess.run([sys.executable, "-c", code], env=env, check=True)
 
@@ -470,7 +474,8 @@ def galaxy_serving_demo():
         "      f'stats={eng.stats}')\n"
     )
     env = dict(os.environ, PYTHONPATH=SRC,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
     print("Galaxy serving on an uneven 3:2:2:1 plan (planner -> ExecPlan -> engine):")
     subprocess.run([sys.executable, "-c", code], env=env, check=True)
 

@@ -40,9 +40,9 @@ counts enter the valid-length kernels as scalar-prefetch operands and the
 grids *skip* pad blocks, so executed MXU work tracks the plan's assigned
 units.  The decode attention core stays XLA (it is a block-table gather,
 not an MXU-bound GEMM); its projections shed like everything else.
-Pallas inside shard_map needs ``check_rep=False`` (no replication rule for
-``pallas_call``), so that flag flips only on the pallas path and the xla
-graphs stay bit-identical to before.
+Pallas inside ``jax.shard_map`` needs ``check_vma=False`` (``pallas_call``
+has no varying-manual-axes rule), so that flag flips only on the pallas
+path and the xla graphs stay bit-identical to before.
 
 Serving path: ``hmp_prefill`` / ``hmp_decode`` run a *stack* of layers
 through the Galaxy schedule against a head-sharded KV cache — prefill is
@@ -81,7 +81,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.execplan import ExecPlan, SeqLayout
 from repro.core.ring import (
@@ -415,13 +414,13 @@ def hmp_layer(p: Dict, x, mesh: Mesh, *, overlap: bool = False,
     """
     p, layout = _validate_plan(p, x, mesh, plan, seq=seq)
     backend = plan.compute_backend if plan is not None else "xla"
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_hmp_layer_local, overlap=overlap, layout=layout,
                           plan=plan, backend=backend),
         mesh=mesh,
         in_specs=(layer_param_specs(), P(None, AXIS, None)),
         out_specs=P(None, AXIS, None),
-        check_rep=backend == "xla",  # pallas_call has no replication rule
+        check_vma=backend == "xla",  # pallas_call has no replication rule
     )
     return fn(p, x)
 
@@ -505,13 +504,13 @@ def _prefill_dense(layers: Sequence[Dict], x, mesh: Mesh, cache: List[Dict],
     layers = [p for p, _ in validated]
     layout = validated[0][1] if validated else None
     backend = plan.compute_backend
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_prefill_layer_local, overlap=overlap, layout=layout,
                           plan=plan, backend=backend),
         mesh=mesh,
         in_specs=(layer_param_specs(), P(None, AXIS, None), CACHE_SPEC, CACHE_SPEC),
         out_specs=(P(None, AXIS, None), CACHE_SPEC, CACHE_SPEC),
-        check_rep=backend == "xla",
+        check_vma=backend == "xla",
     )
     new_cache = []
     for p, c in zip(layers, cache):
@@ -611,12 +610,12 @@ def _decode_dense(layers: Sequence[Dict], x, mesh: Mesh, cache: List[Dict],
                   index, *, plan: ExecPlan):
     layers = [_validate_plan(p, None, mesh, plan)[0] for p in layers]
     backend = plan.compute_backend
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_decode_layer_local, plan=plan, backend=backend),
         mesh=mesh,
         in_specs=(layer_param_specs(), P(), CACHE_SPEC, CACHE_SPEC, P()),
         out_specs=(P(), CACHE_SPEC, CACHE_SPEC),
-        check_rep=backend == "xla",
+        check_vma=backend == "xla",
     )
     index = jnp.asarray(index, jnp.int32)
     if index.ndim == 0:
@@ -737,13 +736,13 @@ def _prefill_paged(layers: Sequence[Dict], x, mesh: Mesh,
         extras = (jnp.asarray(block_row, jnp.int32), offset)
     phys = block_row[pos // page_size].astype(jnp.int32)
     within = (pos % page_size).astype(jnp.int32)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(layer_param_specs(), P(None, AXIS, None),
                   PAGED_CACHE_SPEC, PAGED_CACHE_SPEC, P(), P(), *extra_specs),
         out_specs=(P(None, AXIS, None), PAGED_CACHE_SPEC, PAGED_CACHE_SPEC),
-        check_rep=backend == "xla",
+        check_vma=backend == "xla",
     )
     new_pages = []
     for p, c in zip(layers, pages):
@@ -833,13 +832,13 @@ def _decode_paged(layers: Sequence[Dict], x, mesh: Mesh,
     absolute positions.  Returns (y, pages) with y replicated."""
     layers = [_validate_plan(p, None, mesh, plan)[0] for p in layers]
     backend = plan.compute_backend
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_decode_paged_layer_local, plan=plan, backend=backend),
         mesh=mesh,
         in_specs=(layer_param_specs(), P(), PAGED_CACHE_SPEC, PAGED_CACHE_SPEC,
                   P(), P()),
         out_specs=(P(), PAGED_CACHE_SPEC, PAGED_CACHE_SPEC),
-        check_rep=backend == "xla",
+        check_vma=backend == "xla",
     )
     block_table = jnp.asarray(block_table, jnp.int32)
     positions = jnp.asarray(positions, jnp.int32)
@@ -885,12 +884,12 @@ def _megatron_layer_local(p, x, *, plan: Optional[ExecPlan] = None,
 def megatron_layer(p: Dict, x, mesh: Mesh, *, plan: Optional[ExecPlan] = None):
     p, _ = _validate_plan(p, None, mesh, plan)
     backend = plan.compute_backend if plan is not None else "xla"
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_megatron_layer_local, plan=plan, backend=backend),
         mesh=mesh,
         in_specs=(layer_param_specs(), P()),
         out_specs=P(),
-        check_rep=backend == "xla",
+        check_vma=backend == "xla",
     )
     return fn(p, x)
 
@@ -926,7 +925,7 @@ def _sp_layer_local(p, x_loc):
 
 def sp_layer(p: Dict, x, mesh: Mesh, *, plan: Optional[ExecPlan] = None):
     # SP replicates weights: an uneven TP plan does not apply
-    fn = shard_map(
+    fn = jax.shard_map(
         _sp_layer_local,
         mesh=mesh,
         in_specs=(layer_param_specs(sp=True), P(None, AXIS, None)),

@@ -93,33 +93,23 @@ def _perm(axis_size: int, shift: int = 1):
     return [(i, (i + shift) % axis_size) for i in range(axis_size)]
 
 
-def _axis_size(axis_name: str) -> int:
-    # jax.lax.axis_size is missing from older jax; psum of a literal 1
-    # constant-folds to the (static) axis size on every version.
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _hop_permute(seg, axis_name: str, pairs, d: int):
     """Rotate ``seg`` one ring position for the devices named in ``pairs``.
 
     ``pairs`` must be a subset of the +1 rotation.  Devices not named as a
     destination receive exact zeros (lax.ppermute's partial-permutation
-    semantics) — under vmap-emulated rings, whose ppermute batching rule
-    insists on a full permutation, the same semantics are encoded as a
-    sender-side gate followed by a full rotation.
+    semantics).  A ring emulated with ``jax.vmap(axis_name=...)`` (the
+    property tests) has no mesh axis, and vmap's ppermute insists on a full
+    permutation: there the same semantics are encoded as a sender-side gate
+    followed by a full rotation.
     """
-    if len(pairs) == d:
+    if len(pairs) == d or axis_name in jax.sharding.get_abstract_mesh().manual_axes:
         return jax.lax.ppermute(seg, axis_name, pairs)
-    try:
-        return jax.lax.ppermute(seg, axis_name, pairs)
-    except Exception:
-        ships = np.zeros(d, dtype=bool)
-        ships[[src for src, _ in pairs]] = True
-        idx = jax.lax.axis_index(axis_name)
-        gated = jnp.where(jnp.asarray(ships)[idx], seg, jnp.zeros_like(seg))
-        return jax.lax.ppermute(gated, axis_name, _perm(d))
+    ships = np.zeros(d, dtype=bool)
+    ships[[src for src, _ in pairs]] = True
+    idx = jax.lax.axis_index(axis_name)
+    gated = jnp.where(jnp.asarray(ships)[idx], seg, jnp.zeros_like(seg))
+    return jax.lax.ppermute(gated, axis_name, _perm(d))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -308,14 +298,7 @@ class RingSchedule:
 
 def _pin(*vals):
     """Pin ``vals`` on opposite sides of the scheduler (identity on values)."""
-    if not hasattr(jax.lax, "optimization_barrier"):
-        return vals
-    try:
-        return jax.lax.optimization_barrier(vals)
-    except NotImplementedError:
-        # vmap-emulated rings have no batching rule for the barrier; program
-        # order alone still issues the hop before the GEMM consuming it.
-        return vals
+    return jax.lax.optimization_barrier(vals)
 
 
 def _resolve_allgather(schedule: Optional[RingSchedule], *, d: int,
@@ -376,7 +359,7 @@ def ring_allgather_matmul(x_local, w_local, axis_name: str,
     Step r computes the GEMM for the tile received r hops ago while the next
     tile is in flight; the final step does no communication (paper §III-D-1).
     """
-    d = _axis_size(axis_name)
+    d = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, _ = x_local.shape
     sched = _resolve_allgather(schedule, d=d, s_loc=s_loc)
@@ -431,7 +414,7 @@ def matmul_ring_reducescatter(h_local, w_local, axis_name: str,
     predecessor, which processed the same tile one step earlier.  After D
     steps device i owns the fully-reduced tile i.
     """
-    d = _axis_size(axis_name)
+    d = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s, _ = h_local.shape
     sched = _resolve_scatter(schedule, d=d, s=s)
@@ -480,7 +463,7 @@ def sync_allgather_matmul(x_local, w_local, axis_name: str,
     Transport mode and double buffering are ring-only concerns and are
     ignored here; only the schedule's valid row counts and gemm hook apply.
     """
-    d = _axis_size(axis_name)
+    d = jax.lax.axis_size(axis_name)
     sched = _resolve_allgather(schedule, d=d, s_loc=x_local.shape[1])
     vs = sched.valid_sizes if sched.is_masked else None
     xg = jax.lax.all_gather(x_local, axis_name, axis=1, tiled=True)
@@ -498,7 +481,7 @@ def sync_allgather_matmul(x_local, w_local, axis_name: str,
 def sync_matmul_reducescatter(h_local, w_local, axis_name: str,
                               *, schedule: Optional[RingSchedule] = None):
     """Unoverlapped oracle for ``matmul_ring_reducescatter``."""
-    d = _axis_size(axis_name)
+    d = jax.lax.axis_size(axis_name)
     sched = _resolve_scatter(schedule, d=d, s=h_local.shape[1])
     vs = sched.valid_sizes if sched.is_masked else None
     if vs is not None:

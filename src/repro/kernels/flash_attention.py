@@ -30,7 +30,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tiled_gemm import divisor_block
+from repro.kernels.tiled_gemm import LANE, SUBLANE, aligned_block
 
 NEG_INF = -1e30
 
@@ -135,18 +135,14 @@ def attention_block_map(positions, block_q: int, block_k: int) -> np.ndarray:
     rows).  A (q-block, k-block) pair is live iff some valid key in the
     k-block is causally visible to some valid query in the q-block; for a
     dense ``arange`` layout this reduces to the standard causal block skip.
-    The layout is trace-time static (it comes from ``ExecPlan.seq_layout``),
-    so the map is plain numpy and enters the kernel as a scalar-prefetch
-    operand.
+    Blocks need not divide the rows: the last block of each axis is cut
+    short (its tail holds no position).  The layout is trace-time static
+    (it comes from ``ExecPlan.seq_layout``), so the map is plain numpy and
+    enters the kernel as a scalar-prefetch operand.
     """
     pos = np.asarray(positions, int)
     (s,) = pos.shape
-    if s % block_q or s % block_k:
-        raise ValueError(
-            f"positions ({s} rows) do not tile into blocks "
-            f"(block_q={block_q}, block_k={block_k})"
-        )
-    nq, nk = s // block_q, s // block_k
+    nq, nk = -(-s // block_q), -(-s // block_k)
     live = np.zeros((nq, nk), np.int32)
     for qi in range(nq):
         qp = pos[qi * block_q:(qi + 1) * block_q]
@@ -162,7 +158,8 @@ def attention_block_map(positions, block_q: int, block_k: int) -> np.ndarray:
 
 
 def _ragged_kernel(vh_ref, bm_ref, q_ref, k_ref, v_ref, pq_ref, pk_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale: float):
+                   m_scr, l_scr, acc_scr, *, scale: float, s: int,
+                   block_k: int):
     hi = pl.program_id(1)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -182,22 +179,28 @@ def _ragged_kernel(vh_ref, bm_ref, q_ref, k_ref, v_ref, pq_ref, pk_ref, o_ref,
         q = q_ref[0, 0].astype(jnp.float32)   # (block_q, hd)
         kk = k_ref[0, 0].astype(jnp.float32)  # (block_k, hd)
         vv = v_ref[0, 0].astype(jnp.float32)
+        if s % block_k:
+            # the last key block reads past the end of v, where the buffer
+            # may hold non-finite values that a zero probability would not
+            # cancel
+            krow = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, vv.shape, 0)
+            vv = jnp.where(krow < s, vv, 0.0)
 
-        s = jnp.dot(q, kk.T, preferred_element_type=jnp.float32) * scale
-        pq = pq_ref[...]
-        pk = pk_ref[...]
-        mask = (pq[:, None] >= 0) & (pk[None, :] >= 0) \
-            & (pk[None, :] <= pq[:, None])
-        s = jnp.where(mask, s, NEG_INF)
+        sc = jnp.dot(q, kk.T, preferred_element_type=jnp.float32) * scale
+        pq = pq_ref[...]  # (block_q, 1) query positions, -1 = pad
+        pk = pk_ref[...]  # (1, block_k) key positions, -1 = pad
+        mask = (pq >= 0) & (pk >= 0) & (pk <= pq)
+        sc = jnp.where(mask, sc, NEG_INF)
 
         m_prev = m_scr[...]
         l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         # fully-masked rows keep the accumulator stable (exp guard)
-        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(sc > NEG_INF / 2, jnp.exp(sc - m_new), 0.0)
         alpha = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_new), 0.0)
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
             p, vv, preferred_element_type=jnp.float32
         )
         m_scr[...] = m_new
@@ -206,7 +209,7 @@ def _ragged_kernel(vh_ref, bm_ref, q_ref, k_ref, v_ref, pq_ref, pk_ref, o_ref,
     def _finish():
         # rows with no live contribution (pad queries, pad heads) emit zero
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def ragged_flash_attention(
@@ -221,24 +224,35 @@ def ragged_flash_attention(
     and pruned (q, k) block pairs are skipped entirely, pad query rows come
     out exactly zero, and valid rows match ``flash_attention_ref`` over the
     compacted sequence.
+
+    Blocks are (8, 128)-aligned or span the sequence
+    (``tiled_gemm.aligned_block``); the positions are staged as a (S, 1)
+    query column and a (1, S) key row, padded with -1 past the sequence,
+    so the masked tail of the last block never attends or is attended.
     """
     b, h, s, hd = q.shape
     hkv = k.shape[1]
     g = h // hkv
-    block_q = divisor_block(s, block_q)
-    block_k = divisor_block(s, block_k)
+    block_q = aligned_block(s, block_q, SUBLANE)
+    # key positions ride the lane axis of their (1, block_k) block
+    block_k = aligned_block(s, block_k, LANE)
+    nq, nk = pl.cdiv(s, block_q), pl.cdiv(s, block_k)
     scale = 1.0 / (hd ** 0.5)
 
-    block_map = attention_block_map(positions, block_q, block_k)
+    pos = np.asarray(positions, np.int32)
+    block_map = attention_block_map(pos, block_q, block_k)
+    pos_q = np.full(nq * block_q, -1, np.int32)
+    pos_q[:s] = pos
+    pos_k = np.full(nk * block_k, -1, np.int32)
+    pos_k[:s] = pos
     vh = jnp.asarray(h if valid_heads is None else valid_heads,
                      jnp.int32).reshape(1)
-    pos = jnp.asarray(positions, jnp.int32)
 
-    grid = (b, h, s // block_q, s // block_k)
-    kernel = functools.partial(_ragged_kernel, scale=scale)
+    kernel = functools.partial(_ragged_kernel, scale=scale, s=s,
+                               block_k=block_k)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # valid_heads, block skip map
-        grid=grid,
+        grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda bi, hi, qi, ki, vh, bm: (bi, hi, qi, 0)),
@@ -246,14 +260,14 @@ def ragged_flash_attention(
                          lambda bi, hi, qi, ki, vh, bm: (bi, hi // g, ki, 0)),
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda bi, hi, qi, ki, vh, bm: (bi, hi // g, ki, 0)),
-            pl.BlockSpec((block_q,), lambda bi, hi, qi, ki, vh, bm: (qi,)),
-            pl.BlockSpec((block_k,), lambda bi, hi, qi, ki, vh, bm: (ki,)),
+            pl.BlockSpec((block_q, 1), lambda bi, hi, qi, ki, vh, bm: (qi, 0)),
+            pl.BlockSpec((1, block_k), lambda bi, hi, qi, ki, vh, bm: (0, ki)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda bi, hi, qi, ki, vh, bm: (bi, hi, qi, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
     )
@@ -262,4 +276,5 @@ def ragged_flash_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
         interpret=interpret,
-    )(vh, jnp.asarray(block_map), q, k, v, pos, pos)
+    )(vh, jnp.asarray(block_map), q, k, v,
+      jnp.asarray(pos_q)[:, None], jnp.asarray(pos_k)[None, :])

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiled_gemm import SUBLANE
+
 
 def _kernel(x_ref, res_ref, mask_ref, scale_ref, bias_ref, o_ref, *,
             rate: float, eps: float):
@@ -35,15 +37,19 @@ def fused_connective(
     x, res, keep_mask, scale, bias, *, rate: float = 0.0, eps: float = 1e-5,
     block_s: int = 256, interpret: bool = False,
 ):
-    """x, res, keep_mask: (S, d); scale, bias: (d,).  One pass over HBM."""
+    """x, res, keep_mask: (S, d); scale, bias: (d,).  One pass over HBM.
+
+    ``block_s`` must be a multiple of 8 (the sublane tile) or cover all
+    rows; rows are independent, so a last block that runs past the array
+    needs no mask (its tail rows are dropped by the output store)."""
     s, d = x.shape
     block_s = min(block_s, s)
-    if s % block_s:
+    if block_s != s and block_s % SUBLANE:
         raise ValueError(
-            f"connective of {s} rows does not tile into block_s={block_s} "
-            "blocks; the block must divide the row count"
+            f"connective block_s={block_s} is neither a multiple of "
+            f"{SUBLANE} nor all {s} rows"
         )
-    grid = (s // block_s,)
+    grid = (pl.cdiv(s, block_s),)
     kernel = functools.partial(_kernel, rate=rate, eps=eps)
     return pl.pallas_call(
         kernel,

@@ -1,9 +1,10 @@
 """Public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run with ``interpret=True`` (the kernel
-body executes in Python via the Pallas interpreter — functionally identical
-to the TPU lowering).  On a real TPU backend ``interpret`` defaults to
-False and the same calls compile to Mosaic.
+Off the TPU (the CPU test runs) the kernels run with ``interpret=True``
+(the Pallas interpreter executes the kernel body — functionally identical
+to the TPU lowering).  On a TPU backend ``interpret`` is False and the same
+calls compile to Mosaic; ``chip_smoke.py`` checks that the served programs
+contain the Mosaic custom call.
 
 Besides the standalone jit'd wrappers, this module is the dispatch point of
 the ``ExecPlan.compute_backend`` knob: :func:`gemm` and
@@ -25,7 +26,6 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.flash_attention import ragged_flash_attention as _ragged_flash
 from repro.kernels.fused_connective import fused_connective as _connective
-from repro.kernels.tiled_gemm import divisor_block
 from repro.kernels.tiled_gemm import tiled_gemm as _gemm
 from repro.kernels.tiled_gemm import tiled_gemm_valid as _gemm_valid
 
@@ -41,29 +41,27 @@ COMPUTE_BACKENDS = ("xla", "pallas")
 
 def gemm(x, w, *, backend: str = "xla", valid_m=None, valid_n=None,
          valid_k=None, seg_n=None, block_m: int = 128, block_n: int = 128,
-         block_k: int = 512, count_blocks: bool = False):
+         block_k: int = 512):
     """(..., M, K) @ (K, N) through the selected compute backend.
 
-    Leading dims of ``x`` fold into the GEMM M axis as equal segments (one
-    per batch row), each with ``valid_m`` real leading rows.  ``valid_n``
-    names the real leading columns of each ``seg_n``-column segment of
-    ``w`` (e.g. the q/k/v thirds of a fused QKV weight) and ``valid_k`` the
-    real contraction prefix.  Valid counts may be traced scalars — they are
-    per-device quantities inside shard_map.
+    With ``valid_m`` each leading index of ``x`` is one M segment (one per
+    batch row) with ``valid_m`` real leading rows; without it every row is
+    real and the leading dims fold into one M axis (a decode batch is one
+    GEMM).  ``valid_n`` names the real leading columns of each
+    ``seg_n``-column segment of ``w`` (e.g. the q/k/v thirds of a fused QKV
+    weight) and ``valid_k`` the real contraction prefix.  Valid counts may
+    be traced scalars — they are per-device quantities inside shard_map.
 
     xla: a dense dot over the padded shapes with the valid counts applied
     as masks (every pad block still executes — the SPMD oracle), so both
     backends compute the identical function of the valid regions whatever
     the pad regions hold.  pallas: the valid-length tiled kernel, shedding
-    whole pad blocks.  ``count_blocks=True`` (pallas only) also returns
-    the measured live-block count.
+    whole pad blocks.
     """
     if backend not in COMPUTE_BACKENDS:
         raise ValueError(f"unknown compute backend {backend!r}; "
                          f"one of {COMPUTE_BACKENDS}")
     if backend == "xla":
-        if count_blocks:
-            raise ValueError("count_blocks is a pallas-backend measurement")
         m, kk = x.shape[-2], x.shape[-1]
         n = w.shape[1]
         if valid_m is not None:
@@ -78,19 +76,16 @@ def gemm(x, w, *, backend: str = "xla", valid_m=None, valid_n=None,
             keep = (jnp.arange(n) % seg) < valid_n
             out = jnp.where(keep, out, 0)
         return out
-    lead = x.shape[:-2]
-    seg_m = x.shape[-2]
-    x2 = x.reshape(-1, x.shape[-1])
+    if valid_m is None:
+        x3 = x.reshape(1, -1, x.shape[-1])
+    else:
+        x3 = x.reshape(-1, *x.shape[-2:])
     out = _gemm_valid(
-        x2, w, valid_m=valid_m, valid_n=valid_n, valid_k=valid_k,
-        seg_m=seg_m, seg_n=seg_n, block_m=block_m, block_n=block_n,
-        block_k=block_k, count_blocks=count_blocks,
+        x3, w, valid_m=valid_m, valid_n=valid_n, valid_k=valid_k,
+        seg_n=seg_n, block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=_default_interpret(),
     )
-    if count_blocks:
-        out, cnt = out
-        return out.reshape(*lead, seg_m, w.shape[1]), cnt
-    return out.reshape(*lead, seg_m, w.shape[1])
+    return out.reshape(*x.shape[:-1], w.shape[1])
 
 
 def ragged_attention(q, k, v, *, positions, valid_heads=None,
@@ -123,7 +118,7 @@ def connective(x, res, scale, bias, *, block_s: int = 256):
     # than streaming a materialized all-ones buffer through VMEM
     out = _connective(
         x2, res2, x2, scale, bias, rate=0.0,
-        block_s=divisor_block(x2.shape[0], block_s),
+        block_s=block_s,
         interpret=_default_interpret(),
     )
     return out.reshape(*lead, x.shape[-1])

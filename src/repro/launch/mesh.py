@@ -8,45 +8,28 @@ The "model" axis is the Galaxy HMP axis (TP heads/ffn/experts + SP sequence);
 "data" carries batch / FSDP weight shards / long-context cache shards; "pod"
 is the cross-pod (DCN-class) data axis.
 
-``make_mesh_compat`` papers over the jax version split: ``AxisType`` (and
-the ``axis_types=`` kwarg) only exist in newer jax; on older versions plain
-``jax.make_mesh`` already yields Auto-mode axes.  Every mesh in this repo —
-src, tests, benchmarks — should go through it.
+``make_mesh_compat`` builds every mesh in this repo — src, tests,
+benchmarks — with Auto axis types: ``jax.make_mesh`` defaults to Explicit
+axes, and the model zoo's sharding rules rely on GSPMD propagation.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
-
-try:  # jax >= 0.5: explicit-sharding types exist; ask for Auto
-    from jax.sharding import AxisType
-
-    _AUTO = (AxisType.Auto,)
-except ImportError:  # older jax: all mesh axes are Auto-equivalent
-    AxisType = None
-    _AUTO = None
+from jax.sharding import AxisType
 
 
 def make_mesh_compat(shape: Sequence[int], axes: Sequence[str], *,
                      devices: Optional[Sequence] = None):
-    """``jax.make_mesh`` with Auto axis types on any supported jax version.
+    """``jax.make_mesh`` with Auto axis types.
 
     ``devices`` selects an explicit device subset (e.g. the first 4 of 8
     forced host devices, to run a 4-device plan under an 8-device process).
     """
-    if devices is not None:
-        import numpy as np
-        from jax.sharding import Mesh
-
-        arr = np.asarray(devices).reshape(tuple(shape))
-        if _AUTO is not None:
-            return Mesh(arr, tuple(axes), axis_types=_AUTO * len(axes))
-        return Mesh(arr, tuple(axes))
-    if _AUTO is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=_AUTO * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

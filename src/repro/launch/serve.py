@@ -38,6 +38,7 @@ import jax
 import numpy as np
 
 from repro.configs import ASSIGNED_ARCHS, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import (
     Request, SamplerConfig, ServingEngine, TransformerExecutor,
@@ -129,6 +130,7 @@ def main():
                          "executor only — the zoo path is GSPMD-sharded "
                          "and has no padded shards to shed")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduce:

@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import hmp
 from repro.core.execplan import ExecPlan
@@ -72,8 +72,16 @@ class GalaxyHMPExecutor:
         self.plan = plan
         self.mesh = mesh
         self.overlap = overlap
-        self.layers = [plan.ensure_padded(p) for p in layers]
-        self.embed = jnp.asarray(embed)
+        # weights live sharded over the mesh once, as every step reads them
+        # (head/column shards per device; the embedding is replicated)
+        specs = hmp.layer_param_specs()
+        self.layers = [
+            jax.device_put(plan.ensure_padded(p),
+                           {k: NamedSharding(mesh, specs[k]) for k in p})
+            for p in layers
+        ]
+        self.embed = jax.device_put(jnp.asarray(embed),
+                                    NamedSharding(mesh, P()))
         self._prefill_fns: Dict = {}
         self._decode_fn = None
         self._decode_paged_fn = None

@@ -26,6 +26,7 @@ REPO = os.path.join(os.path.dirname(__file__), "..")
 def run_multidevice(body: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # a CPU emulation never competes for a chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = textwrap.dedent(body)
     proc = subprocess.run(
@@ -818,7 +819,6 @@ def test_ring_tile_size_validation():
         import jax, jax.numpy as jnp
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import hmp, ring
         from repro.core.execplan import ExecPlan
         from repro.launch.mesh import make_mesh_compat
@@ -828,7 +828,7 @@ def test_ring_tile_size_validation():
         w = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
         for fn in (ring.matmul_ring_reducescatter, ring.sync_matmul_reducescatter):
             try:
-                shard_map(lambda hl, wl, f=fn: f(hl, wl, 'model'), mesh=mesh,
+                jax.shard_map(lambda hl, wl, f=fn: f(hl, wl, 'model'), mesh=mesh,
                           in_specs=(P(None, None, 'model'), P('model', None)),
                           out_specs=P(None, 'model', None))(h, w)
             except ValueError as e:
@@ -840,7 +840,7 @@ def test_ring_tile_size_validation():
         h2 = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 16))
         bad4 = ring.RingSchedule.dense(4, 4)
         try:
-            shard_map(lambda hl, wl: ring.matmul_ring_reducescatter(
+            jax.shard_map(lambda hl, wl: ring.matmul_ring_reducescatter(
                           hl, wl, 'model', schedule=bad4), mesh=mesh,
                       in_specs=(P(None, None, 'model'), P('model', None)),
                       out_specs=P(None, 'model', None))(h2, w)

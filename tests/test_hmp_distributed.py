@@ -17,6 +17,7 @@ REPO = os.path.join(os.path.dirname(__file__), "..")
 def run_multidevice(body: str, devices: int = 4) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # a CPU emulation never competes for a chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = textwrap.dedent(body)
     proc = subprocess.run(
@@ -52,7 +53,6 @@ def test_ring_primitives_match_sync():
     run_multidevice("""
         import functools, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import ring
         from repro.launch.mesh import make_mesh_compat
         mesh = make_mesh_compat((4,), ('model',))
@@ -62,7 +62,7 @@ def test_ring_primitives_match_sync():
         w2 = jax.random.normal(jax.random.PRNGKey(3), (64, 16))
 
         def ag(fn):
-            return shard_map(lambda xl, wl: fn(xl, wl, 'model'), mesh=mesh,
+            return jax.shard_map(lambda xl, wl: fn(xl, wl, 'model'), mesh=mesh,
                              in_specs=(P(None,'model',None), P(None,'model')),
                              out_specs=P(None,None,'model'))
         out_r = ag(ring.ring_allgather_matmul)(x, w1)
@@ -72,7 +72,7 @@ def test_ring_primitives_match_sync():
         assert float(jnp.abs(out_r - expected).max()) < 1e-5
 
         def rs(fn):
-            return shard_map(lambda hl, wl: fn(hl, wl, 'model'), mesh=mesh,
+            return jax.shard_map(lambda hl, wl: fn(hl, wl, 'model'), mesh=mesh,
                              in_specs=(P(None,None,'model'), P('model',None)),
                              out_specs=P(None,'model',None))
         out_r = rs(ring.matmul_ring_reducescatter)(h, w2)

@@ -75,11 +75,11 @@ def test_kernel_shape_errors_are_valueerrors():
     with pytest.raises(ValueError, match="block_s"):
         fused_connective(jnp.zeros((100, 8)), jnp.zeros((100, 8)),
                          jnp.zeros((100, 8)), jnp.ones(8), jnp.zeros(8),
-                         block_s=32, interpret=True)
+                         block_s=12, interpret=True)
     from repro.kernels.tiled_gemm import tiled_gemm_valid
 
-    with pytest.raises(ValueError, match="seg_m"):
-        tiled_gemm_valid(x, w, seg_m=48, interpret=True)
+    with pytest.raises(ValueError, match="seg_n"):
+        tiled_gemm_valid(x, w, seg_n=40, interpret=True)
 
 
 def test_valid_gemm_matches_dense_when_fully_valid():
@@ -94,19 +94,39 @@ def test_valid_gemm_matches_dense_when_fully_valid():
                                atol=5e-4)
 
 
+@pytest.mark.parametrize("m,n,k,kw,blocks", [
+    # fused QKV: three 512-column segments, 320 valid each -> 3 of 4 N
+    # blocks per segment; K=1280 takes 3 blocks of 512 (masked tail)
+    (64, 1536, 1280, dict(valid_n=320, seg_n=512), 1 * 9 * 3),
+    # 192-column segments: block 1 straddles into segment 1's valid
+    # prefix, block 3 starts segment 2 -> blocks 0, 1, 3 live
+    (8, 576, 256, dict(valid_n=32, seg_n=192), 1 * 3 * 1),
+    (200, 256, 1280, dict(valid_m=5, valid_k=100), 1 * 2 * 1),
+])
+def test_dense_block_count(m, n, k, kw, blocks):
+    """The analytic live-block count under the kernel's aligned blocks."""
+    from repro.kernels.tiled_gemm import dense_block_count
+
+    assert dense_block_count(m, n, k, **kw) == blocks
+
+
 def test_ops_gemm_backend_dispatch():
     """ops.gemm: xla == pallas on clean (zero-padded) operands; batched
-    inputs fold into M segments; unknown backends are rejected."""
+    inputs are M segments with their own valid rows, or fold into one M
+    axis when every row is valid; unknown backends are rejected."""
     x = jax.random.normal(KEY, (2, 8, 16), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (16, 12), jnp.float32)
     w = w.at[:, 9:].set(0)  # pad columns zero, as ExecPlan materializes
     dense = ops.gemm(x, w, backend="xla")
     shed = ops.gemm(x, w, backend="pallas", valid_n=9, block_n=3)
     np.testing.assert_allclose(np.asarray(shed), np.asarray(dense), atol=1e-5)
+    xr = x.at[:, 5:].set(0)  # each batch row's segment holds 5 real rows
+    shed_m = ops.gemm(xr, w, backend="pallas", valid_m=5, valid_n=9)
+    np.testing.assert_allclose(np.asarray(shed_m),
+                               np.asarray(ops.gemm(xr, w, backend="xla")),
+                               atol=1e-5)
     with pytest.raises(ValueError, match="backend"):
         ops.gemm(x, w, backend="cuda")
-    with pytest.raises(ValueError, match="count_blocks"):
-        ops.gemm(x, w, backend="xla", count_blocks=True)
 
 
 def test_ops_wrappers_jit():

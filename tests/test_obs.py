@@ -509,6 +509,7 @@ def test_traced_serve_galaxy_acceptance():
     bitwise-unchanged by telemetry."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"  # a CPU emulation never competes for a chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = textwrap.dedent("""
     import jax

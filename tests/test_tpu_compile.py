@@ -1,0 +1,139 @@
+"""The main-path Pallas kernels compile for a TPU v5e at the widths served.
+
+The compile cases hand the kernels to the TPU compiler for a chip that is
+described, not attached (``jax.experimental.topologies``): Mosaic refuses
+blocks that break its (8, 128) tiling or store scalars to VMEM, which
+interpret mode never sees.  The topology is described inside a
+module-scoped fixture, so every test worker collects the same tests and
+only the worker given this file loads the TPU library; where it cannot be
+described the compile cases skip.  The persistent compilation cache is off
+around these compiles (an entry compiled for a described chip cannot be
+read back without one).
+
+The interpret-mode cases pin the aligned block choice at extents the
+blocks do not divide (gpt2-l's K=1280, qwen1.5-0.5b's 704 columns per chip
+on four chips): the masked tail blocks leave the result bitwise equal to
+the padded-XLA oracle (integer-valued operands make every summation order
+exact).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.kernels import ops
+from repro.kernels.flash_attention import ragged_flash_attention
+from repro.kernels.fused_connective import fused_connective
+from repro.kernels.tiled_gemm import LANE, SUBLANE, aligned_block, tiled_gemm_valid
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _gemm_stack(x, wqkv, wo, w1, w2, heads, cols):
+    """The four per-shard GEMMs of one Galaxy layer (pallas backend)."""
+    hd = 64
+    return (tiled_gemm_valid(x, wqkv, valid_m=x.shape[1], valid_n=heads * hd,
+                             seg_n=wqkv.shape[1] // 3),
+            tiled_gemm_valid(x, wo, valid_k=heads * hd),
+            tiled_gemm_valid(x, w1, valid_n=cols),
+            tiled_gemm_valid(x @ w1, w2, valid_k=cols))
+
+
+def _qwen_chip_gemms(x, w1, w2, cols):
+    # qwen1.5-0.5b's d_ff 2816 over four chips: 704 columns each
+    return (tiled_gemm_valid(x, w1, valid_n=cols),
+            tiled_gemm_valid(x @ w1, w2, valid_k=cols))
+
+
+def _ragged(q, k, v, valid_heads):
+    return ragged_flash_attention(q, k, v, positions=np.arange(q.shape[2]),
+                                  valid_heads=valid_heads)
+
+
+def _connective(x, res, scale, bias):
+    return fused_connective(x, res, x, scale, bias)
+
+
+# (case, fn, (shape, dtype) per argument); the kernels are called with
+# interpret=False (the default), so they lower to Mosaic
+CASES = {
+    # gpt2-l on one chip: d_model 1280 (K=1280 leaves a masked 256-row
+    # tail of the 512-deep K blocks), 20 heads x 64, d_ff 5120, 512 rows
+    "gemm-gpt2l-prefill": (
+        _gemm_stack,
+        [((1, 512, 1280), jnp.float32), ((1280, 3840), jnp.float32),
+         ((1280, 1280), jnp.float32), ((1280, 5120), jnp.float32),
+         ((5120, 1280), jnp.float32), ((), jnp.int32), ((), jnp.int32)]),
+    "gemm-qwen-704-per-chip": (
+        _qwen_chip_gemms,
+        [((1, 512, 1024), jnp.bfloat16), ((1024, 704), jnp.bfloat16),
+         ((704, 1024), jnp.bfloat16), ((), jnp.int32)]),
+    # decode: a batch of 8 slots is the M axis (8 rows, one sublane tile)
+    "gemm-decode-batch8": (
+        lambda x, w1, cols: tiled_gemm_valid(x, w1, valid_n=cols),
+        [((1, 8, 1280), jnp.float32), ((1280, 5120), jnp.float32),
+         ((), jnp.int32)]),
+    "ragged-flash-1x20x1024x64": (
+        _ragged,
+        [((1, 20, 1024, 64), jnp.float32)] * 3 + [((), jnp.int32)]),
+    "fused-connective-1024x1280": (
+        _connective,
+        [((1024, 1280), jnp.float32)] * 2 + [((1280,), jnp.float32)] * 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, args = CASES[case]
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("extent,preferred,align,block", [
+    (1280, 512, LANE, 512),    # K: 2.5 blocks, the tail is masked
+    (704, 128, LANE, 128),     # N: 5.5 blocks
+    (8, 128, SUBLANE, 8),      # a decode batch spans its axis
+    (1, 128, SUBLANE, 1),
+    (200, 128, SUBLANE, 128),
+    (300, 100, SUBLANE, 96),   # rounded down to the sublane unit
+])
+def test_aligned_block(extent, preferred, align, block):
+    assert aligned_block(extent, preferred, align) == block
+
+
+@pytest.mark.parametrize("m,k,n,valid_k,valid_n", [
+    (64, 1280, 256, None, None),
+    (64, 1280, 256, 1100, None),
+    (64, 256, 704, None, 650),
+    (8, 1280, 704, 1000, 600),
+])
+def test_valid_gemm_tail_blocks_bitwise(m, k, n, valid_k, valid_n):
+    rng = np.random.default_rng(k + n)
+    x = jnp.asarray(rng.integers(-3, 4, (1, m, k)), jnp.float32)
+    w = jnp.asarray(rng.integers(-3, 4, (k, n)), jnp.float32)
+    out = tiled_gemm_valid(x, w, valid_k=valid_k, valid_n=valid_n,
+                           interpret=True)
+    oracle = ops.gemm(x, w, backend="xla", valid_k=valid_k, valid_n=valid_n)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
