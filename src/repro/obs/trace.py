@@ -1,17 +1,44 @@
 """Span tracing: where did this request's milliseconds go?
 
-A :class:`Tracer` records structured spans for every serving lifecycle
-event and exports them as Chrome trace-event JSON (the ``traceEvents``
-array format), so a serve run opens directly in ``chrome://tracing`` or
-https://ui.perfetto.dev.
+Two sinks, one call per span site.  :func:`span` always enters a
+``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` for an engine
+step) under a stable name: nearly free when no profiler runs (about a
+microsecond), and inside a ``jax.profiler`` capture an event on the host
+plane of the same ``.xplane.pb`` as the device's programs and ops, on the
+profiler's clock, with the span's keyword arguments as event stats.  When a
+:class:`Tracer` is passed and enabled, the same call also records the span
+in the Tracer's Chrome trace-event JSON (the ``traceEvents`` array format:
+open a serve run in ``chrome://tracing`` or https://ui.perfetto.dev).
+Tracing is on exactly when a profiler session is running or a Tracer is
+attached; there is no other switch.
 
-Model
------
+Span names are fixed, since readers key on them (``perfbench/progspans.py``):
+
+* ``engine.step`` (``step_num``) — one continuous-scheduler iteration;
+  inside it ``engine.admit`` (args ``admitted``, ``queue``),
+  ``engine.prefix_lookup``, ``engine.prefill`` (one prompt chunk's host
+  work, executor call included), ``engine.decode.prepare`` (tokens,
+  positions, block table and their transfer), ``engine.decode.dispatch``
+  (the executor call), ``engine.sample`` (RNG split and the sampling
+  dispatch), ``engine.sync`` (the one place the host blocks on the device:
+  the sampled tokens' readback), ``engine.emit`` (the per-slot emit/retire
+  loop, one span per step), ``engine.spec_round`` / ``engine.spec_propose``
+  / ``engine.spec_verify`` (speculative rounds);
+* ``galaxy.prefill`` / ``galaxy.decode`` — the Galaxy executor's own host
+  time around its jitted call (pytree flattening and dispatch).
+
+The Tracer's JSON clock is a private ``perf_counter`` origin, so only the
+profiler's events line up with the device trace.
+
+Model of the JSON
+-----------------
 * One *process* (``pid``) per tracer; one *thread track* (``tid``) per
-  label — the engine uses the ``"engine"`` track for its loop steps
-  (admit / prefill_chunk / decode_step / spec_round) and one ``"req <uid>"``
-  track per request for its lifecycle phases (queued → prefill → decode),
-  which tile the request's submit→retire wall time contiguously.
+  label — the engine's spans go on the ``"engine"`` track, and one
+  ``"req <uid>"`` track per request holds its lifecycle phases (queued →
+  prefill → decode, :class:`RequestTracks`), which tile the request's
+  submit→retire wall time contiguously.  Request phases overlap across
+  requests and do not nest on the engine thread, so they live in the JSON
+  only, never in the profiler.
 * Spans follow strict stack discipline per track: :meth:`Tracer.begin`
   pushes, :meth:`Tracer.end` pops and emits one *complete* event
   (``ph="X"`` with ``ts``/``dur`` in microseconds).  Stack discipline makes
@@ -21,13 +48,11 @@ Model
 
 Overhead discipline: tracing never synchronizes the device (no
 ``block_until_ready``); span boundaries land on the host-side dispatch
-points the engine already passes through, and the engine only *calls* the
-tracer when one was passed and is enabled — a run without a tracer
-executes zero tracing instructions per token (gated structurally in
-``tests/test_obs.py``).  Host-side timestamps mean an engine-track span
-that ends before the next sync point measures dispatch, not device time;
-the request-phase spans end on real sync points (a sampled token, a
-retirement) and are what the >=95 %-coverage acceptance gate reads.
+points the engine already passes through.  The engine only calls the
+Tracer when one was passed and is enabled, so a run without one executes
+zero Tracer instructions, and a bounded number of annotations per engine
+step, never one per token (both gated structurally in
+``tests/test_obs.py``).
 
 :class:`RequestTracks` is the small per-request phase bookkeeper the
 engine drives (and the hypothesis property test in ``tests/test_obs.py``
@@ -43,7 +68,9 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple, Union
 
-__all__ = ["Tracer", "RequestTracks"]
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+__all__ = ["Tracer", "RequestTracks", "span"]
 
 
 class Tracer:
@@ -208,3 +235,49 @@ class RequestTracks:
 
     def open_uids(self) -> List[int]:
         return [uid for uid, ph in self._phase.items() if ph is not None]
+
+
+class _TracedSpan:
+    """A span site with a Tracer attached: the profiler annotation and the
+    Tracer's span open and close together."""
+
+    __slots__ = ("_ann", "_tracer", "_name", "_args", "_end_args")
+
+    def __init__(self, ann, tracer: Tracer, name: str, args: dict):
+        self._ann = ann
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+        self._end_args: dict = {}
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._tracer.begin("engine", self._name, **self._args)
+        return self
+
+    def __exit__(self, *exc):
+        self._tracer.end("engine", **self._end_args)
+        return self._ann.__exit__(*exc)
+
+    def set_metadata(self, **args) -> None:
+        """Arguments known only at the span's end (both sinks)."""
+        self._ann.set_metadata(**args)
+        self._end_args.update(args)
+
+
+def span(name: str, tracer: Optional[Tracer] = None, *,
+         step: Optional[int] = None, **args):
+    """Context manager for one span site.
+
+    Always a ``jax.profiler`` annotation named ``name`` with ``args`` as its
+    stats (``StepTraceAnnotation`` with ``step_num=step`` when ``step`` is
+    given); with an enabled ``tracer``, also a span on its ``"engine"``
+    track.  The object entered has ``set_metadata(**args)`` for arguments
+    known only at the end."""
+    ann = (TraceAnnotation(name, **args) if step is None
+           else StepTraceAnnotation(name, step_num=step, **args))
+    if tracer is None or not tracer.enabled:
+        return ann
+    if step is not None:
+        args["step_num"] = step
+    return _TracedSpan(ann, tracer, name, args)

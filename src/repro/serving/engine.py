@@ -60,9 +60,13 @@ calls on a reused engine).  Two opt-in hooks add the expensive signals:
   whole request lifecycle — submit → queued → admitted (prefix lookup) →
   each prefill chunk → each decode step / speculative round (rollback) →
   retire — on one track per request plus an engine-loop track, exportable
-  as Chrome trace-event JSON.  Tracing never synchronizes the device and a
-  run without a tracer executes zero tracing instructions per token
-  (gated structurally in ``tests/test_obs.py``).
+  as Chrome trace-event JSON.  The engine-loop spans (``engine.step`` and
+  its children, :func:`~repro.obs.trace.span`) are also ``jax.profiler``
+  annotations, always entered, so a profiler capture puts them on the
+  device trace's clock.  Tracing never synchronizes the device; a run
+  without a tracer executes zero Tracer instructions and a bounded number
+  of annotations per step, none per token (gated structurally in
+  ``tests/test_obs.py``).
 * ``drift=`` (:class:`~repro.obs.drift.DriftMonitor`) prices each executed
   step with the planner's simulator and histograms measured/simulated —
   the live costmodel-drift signal.  Drift is a diagnostics mode: it adds
@@ -92,7 +96,7 @@ from repro.models.sharding import Rules, axis_rules
 from repro.models.transformer import apply_model
 from repro.obs.drift import DriftMonitor
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import RequestTracks, Tracer
+from repro.obs.trace import RequestTracks, Tracer, span
 from repro.serving.kvcache import cache_page_size, make_cache, map_cache_leaves
 from repro.serving.kvpool import PagedKVPool
 from repro.serving.prefix_cache import PrefixCache
@@ -522,6 +526,7 @@ class ServingEngine:
         self._trace = tracer if (tracer is not None and tracer.enabled) else None
         self._tracks = (RequestTracks(self._trace)
                         if self._trace is not None else None)
+        self._step = 0  # engine.step number, running across run() calls
         self.drift = drift
         if drift is not None and drift.registry is None:
             drift.registry = self.metrics
@@ -576,15 +581,17 @@ class ServingEngine:
         return getattr(self.executor, "prompt_pad_multiple", 1)
 
     def _sample(self, logits):
-        self.rng, key = jax.random.split(self.rng)
-        return sample(logits, key, self.sampler)
+        with span("engine.sample", self._trace):
+            self.rng, key = jax.random.split(self.rng)
+            return sample(logits, key, self.sampler)
 
     def _sample_positions(self, logits):
         """Per-position sampling for the speculative verify chunk.  Greedy
         (the only mode speculation runs in) consumes no randomness, so the
         RNG split never perturbs token pinning."""
-        self.rng, key = jax.random.split(self.rng)
-        return sample_positions(logits, key, self.sampler)
+        with span("engine.sample", self._trace):
+            self.rng, key = jax.random.split(self.rng)
+            return sample_positions(logits, key, self.sampler)
 
     def _emit(self, r: Request, token: int, limit: int) -> bool:
         """Append one token; returns True if the request just finished.
@@ -652,16 +659,12 @@ class ServingEngine:
         storage = ex.make_pool(total_pages, ps)
         pcache = PrefixCache(pool, grain=grain) if self.prefix_cache else None
         self.pool = pool  # introspection (tests / benches)
-        # telemetry locals: `tr is None` short-circuits every tracing call
-        # site below, so a run without a tracer executes zero tracing
-        # instructions per token (gated structurally in tests/test_obs.py)
+        # telemetry locals: with `tr` None every span site below is a bare
+        # profiler annotation, so a run without a tracer executes zero
+        # Tracer instructions (gated structurally in tests/test_obs.py)
         tr = self._trace
         tracks = self._tracks
         drift = self.drift
-        wire_stats = getattr(ex, "wire_stats", None)
-        if wire_stats is not None:
-            for name, value in wire_stats().items():
-                self.metrics.gauge(name).set(value)
         self._pool_gauges(pool)
         spec = None
         if self.spec_k is not None:
@@ -688,23 +691,23 @@ class ServingEngine:
             off = t.next_off
             size = (t.s_pad - off if chunk_tokens is None
                     else min(chunk_tokens, t.s_pad - off))
-            block_row = jnp.asarray(pool.block_table[t.slot])
-            chunk = jnp.asarray(t.tokens[:, off:off + size])
-            if tr is not None:
-                tr.begin("engine", "prefill_chunk", uid=t.req.uid,
-                         offset=off, rows=size)
             t0 = time.perf_counter() if drift is not None else 0.0
-            if off == 0 and size == t.s_pad:
-                # one-shot program (no context gather): the pre-chunking path
-                logits, storage = ex.prefill_paged(
-                    chunk, storage, block_row, length=t.s)
-            else:
-                logits, storage = ex.prefill_chunk(
-                    chunk, storage, block_row, offset=off, length=t.s)
-                # chunk logits carry every row; the sampled first token
-                # comes from the last *real* prompt token's row
-                logits = logits[:, max(0, min(t.s - 1 - off, size - 1))]
-                self.stats["prefill_chunks"] += 1
+            with span("engine.prefill", tr, uid=t.req.uid, offset=off,
+                      rows=size):
+                block_row = jnp.asarray(pool.block_table[t.slot])
+                chunk = jnp.asarray(t.tokens[:, off:off + size])
+                if off == 0 and size == t.s_pad:
+                    # one-shot program (no context gather): the
+                    # pre-chunking path
+                    logits, storage = ex.prefill_paged(
+                        chunk, storage, block_row, length=t.s)
+                else:
+                    logits, storage = ex.prefill_chunk(
+                        chunk, storage, block_row, offset=off, length=t.s)
+                    # chunk logits carry every row; the sampled first token
+                    # comes from the last *real* prompt token's row
+                    logits = logits[:, max(0, min(t.s - 1 - off, size - 1))]
+                    self.stats["prefill_chunks"] += 1
             if drift is not None:
                 # drift is a diagnostics mode: mid-prompt chunks have no
                 # natural sync point, so pricing their wall time costs one
@@ -712,8 +715,6 @@ class ServingEngine:
                 jax.block_until_ready(logits)
                 drift.observe("prefill_chunk", time.perf_counter() - t0,
                               rows=size, context=off + size)
-            if tr is not None:
-                tr.end("engine")
             # count *computed* prompt tokens: suffix-only under prefix hits
             self.stats["prefill_tokens"] += max(0, min(t.s, off + size) - off)
             t.next_off = off + size
@@ -724,7 +725,9 @@ class ServingEngine:
                 # (the partial tail page stays slot-private); the refcount
                 # algebra is verified at sharing admissions and end of run
                 pcache.insert(t.req.prompt, pool.block_table[t.slot])
-            tok = int(np.asarray(self._sample(logits))[0])
+            sampled = self._sample(logits)
+            with span("engine.sync", tr):
+                tok = int(np.asarray(sampled)[0])
             if self._emit(t.req, tok, t.limit):
                 pool.retire(t.slot)
                 finished.append(t.req)
@@ -740,12 +743,19 @@ class ServingEngine:
             return True
 
         def admit() -> None:
+            with span("engine.admit", tr) as sp:
+                admitted = admit_pass()
+                sp.set_metadata(admitted=admitted, queue=len(self.queue))
+
+        def admit_pass() -> int:
             """Admission: prefix lookup -> shared-page refcount bump ->
-            suffix-only prefill (inline, or queued as chunk tasks)."""
+            suffix-only prefill (inline, or queued as chunk tasks).
+            Returns the number of requests admitted."""
+            admitted = 0
             while self.queue:
                 slot = pool.free_slot()
                 if slot is None:
-                    return
+                    return admitted
                 r = self.queue[0]
                 s = len(r.prompt)
                 limit = min(r.max_new_tokens, self.max_len - s)
@@ -761,12 +771,10 @@ class ServingEngine:
                 shared: List[int] = []
                 cached = 0
                 if pcache is not None:
-                    if tr is not None:
-                        tr.begin("engine", "prefix_lookup", uid=r.uid)
-                    shared, cached = pcache.lookup(r.prompt)
-                    if tr is not None:
-                        tr.end("engine", cached_tokens=cached,
-                               shared_pages=len(shared))
+                    with span("engine.prefix_lookup", tr, uid=r.uid) as sp:
+                        shared, cached = pcache.lookup(r.prompt)
+                        sp.set_metadata(cached_tokens=cached,
+                                        shared_pages=len(shared))
                 if not pool.can_admit(max_positions, shared=len(shared)):
                     if pcache is not None:
                         need = (pool.pages_for(max_positions) - len(shared)
@@ -775,8 +783,9 @@ class ServingEngine:
                         # eviction may have pruned our own match: re-walk
                         shared, cached = pcache.lookup(r.prompt)
                     if not pool.can_admit(max_positions, shared=len(shared)):
-                        return
+                        return admitted
                 self.queue.popleft()
+                admitted += 1
                 pool.admit(slot, initial_positions=s_pad,
                            max_positions=max_positions, shared_pages=shared)
                 self.metrics.gauge("queue_depth").set(len(self.queue))
@@ -801,87 +810,95 @@ class ServingEngine:
                         pass
                 else:
                     prefills.append(task)
+            return admitted
 
         admit()
         while any(slots) or prefills or self.queue:
-            if not any(slots) and not prefills:
-                # nothing active and nothing admissible: drop the whole
-                # prefix tree (its pins may be what starves the head
-                # request) and retry before declaring the pool too small
-                if pcache is not None and len(pcache):
-                    pcache.evict(total_pages)
-                    admit()
-                    if any(slots) or prefills:
-                        continue
-                r = self.queue[0]
-                raise RuntimeError(
-                    f"request uid={r.uid} (prompt {len(r.prompt)}, "
-                    f"max_new {r.max_new_tokens}) cannot fit the pool of "
-                    f"{total_pages} pages x {ps}"
-                )
-            if prefills:
-                # one chunk per iteration, interleaved with the decode step
-                # below: long prompts no longer stall live decode slots
-                if prefill_step(prefills[0]):
-                    prefills.popleft()
-            live = [i for i, sl in enumerate(slots) if sl is not None]
-            if live and spec is not None:
-                # speculative round: draft proposes (batched), the target
-                # verifies each slot's proposals in one chunk prefill,
-                # rejections roll back by block-table truncation
-                storage, done = run_spec_round(
-                    self, spec, slots, live, pool, storage)
-                for i, req in done:
-                    slots[i] = None
-                    finished.append(req)
-                    self._retire_obs(req)
-                if done:
-                    self._pool_gauges(pool)
-            elif live:
-                tokens = np.zeros((n_slots, 1), np.int32)
-                positions = np.zeros(n_slots, np.int32)
-                live_mask = np.zeros(n_slots, bool)
-                for i in live:
-                    pool.ensure(i, slots[i].next_index)
-                    tokens[i, 0] = slots[i].last_token
-                    positions[i] = slots[i].next_index
-                    live_mask[i] = True
-                # non-live rows (idle *or mid-prefill*) decode against the
-                # null page: their dummy write must not touch real pages
-                bt = np.where(live_mask[:, None], pool.block_table, 0)
-                if tr is not None:
-                    tr.begin("engine", "decode_step", live=len(live))
-                t0 = time.perf_counter() if drift is not None else 0.0
-                logits, storage = ex.decode_paged(
-                    jnp.asarray(tokens), storage,
-                    jnp.asarray(bt), jnp.asarray(positions),
-                )
-                self.stats["decode_steps"] += 1
-                self.stats["decode_tokens"] += len(live)
-                toks = np.asarray(self._sample(logits))
-                if drift is not None:
-                    # sampling already synced the step: measured time is
-                    # wall time with no extra block_until_ready
-                    drift.observe("decode", time.perf_counter() - t0,
-                                  rows=1,
-                                  context=int(positions[live].max()) + 1)
-                if tr is not None:
-                    tr.end("engine")
-                retired = False
-                for i in live:
-                    sl = slots[i]
-                    if self._emit(sl.req, int(toks[i]), sl.limit):
-                        pool.retire(i)
+            # one iteration: a prefill chunk, then a decode step or a
+            # speculative round, then the admission it frees room for
+            with span("engine.step", tr, step=self._step):
+                self._step += 1
+                if not any(slots) and not prefills:
+                    # nothing active and nothing admissible: drop the whole
+                    # prefix tree (its pins may be what starves the head
+                    # request) and retry before declaring the pool too small
+                    if pcache is not None and len(pcache):
+                        pcache.evict(total_pages)
+                        admit()
+                        if any(slots) or prefills:
+                            continue
+                    r = self.queue[0]
+                    raise RuntimeError(
+                        f"request uid={r.uid} (prompt {len(r.prompt)}, "
+                        f"max_new {r.max_new_tokens}) cannot fit the pool of "
+                        f"{total_pages} pages x {ps}"
+                    )
+                if prefills:
+                    # one chunk per iteration, interleaved with the decode
+                    # step below: long prompts no longer stall live slots
+                    if prefill_step(prefills[0]):
+                        prefills.popleft()
+                live = [i for i, sl in enumerate(slots) if sl is not None]
+                if live and spec is not None:
+                    # speculative round: draft proposes (batched), the target
+                    # verifies each slot's proposals in one chunk prefill,
+                    # rejections roll back by block-table truncation
+                    storage, done = run_spec_round(
+                        self, spec, slots, live, pool, storage)
+                    for i, req in done:
                         slots[i] = None
-                        finished.append(sl.req)
-                        self._retire_obs(sl.req)
-                        retired = True
-                    else:
-                        sl.last_token = int(toks[i])
-                        sl.next_index += 1
-                if retired:
-                    self._pool_gauges(pool)
-            admit()  # freed slots refill immediately — continuous batching
+                        finished.append(req)
+                        self._retire_obs(req)
+                    if done:
+                        self._pool_gauges(pool)
+                elif live:
+                    with span("engine.decode.prepare", tr):
+                        tokens = np.zeros((n_slots, 1), np.int32)
+                        positions = np.zeros(n_slots, np.int32)
+                        live_mask = np.zeros(n_slots, bool)
+                        for i in live:
+                            pool.ensure(i, slots[i].next_index)
+                            tokens[i, 0] = slots[i].last_token
+                            positions[i] = slots[i].next_index
+                            live_mask[i] = True
+                        # non-live rows (idle *or mid-prefill*) decode
+                        # against the null page: their dummy write must not
+                        # touch real pages
+                        bt = np.where(live_mask[:, None], pool.block_table, 0)
+                        tokens_d, bt_d, positions_d = (
+                            jnp.asarray(tokens), jnp.asarray(bt),
+                            jnp.asarray(positions))
+                    t0 = time.perf_counter() if drift is not None else 0.0
+                    with span("engine.decode.dispatch", tr, live=len(live)):
+                        logits, storage = ex.decode_paged(
+                            tokens_d, storage, bt_d, positions_d)
+                    self.stats["decode_steps"] += 1
+                    self.stats["decode_tokens"] += len(live)
+                    sampled = self._sample(logits)
+                    with span("engine.sync", tr):
+                        toks = np.asarray(sampled)
+                    if drift is not None:
+                        # sampling already synced the step: measured time is
+                        # wall time with no extra block_until_ready
+                        drift.observe("decode", time.perf_counter() - t0,
+                                      rows=1,
+                                      context=int(positions[live].max()) + 1)
+                    with span("engine.emit", tr):
+                        retired = False
+                        for i in live:
+                            sl = slots[i]
+                            if self._emit(sl.req, int(toks[i]), sl.limit):
+                                pool.retire(i)
+                                slots[i] = None
+                                finished.append(sl.req)
+                                self._retire_obs(sl.req)
+                                retired = True
+                            else:
+                                sl.last_token = int(toks[i])
+                                sl.next_index += 1
+                        if retired:
+                            self._pool_gauges(pool)
+                admit()  # freed slots refill at once: continuous batching
         # (spec_acceptance is derived on read by the stats facade)
         if pcache is not None:
             pool.check()  # final refcount-algebra validation for the run
@@ -952,16 +969,14 @@ class ServingEngine:
         if tracks is not None:
             for r in live:
                 tracks.phase(r.uid, "prefill", wave=True)
-        if tr is not None:
-            tr.begin("engine", "wave_prefill", batch=b, s_pad=s_pad)
-        if uniform:
-            logits, cache = self.executor.prefill(jnp.asarray(tokens), cache)
-        else:
-            logits, cache = self.executor.prefill(
-                jnp.asarray(tokens), cache, lengths=jnp.asarray(lengths)
-            )
-        if tr is not None:
-            tr.end("engine")
+        with span("engine.prefill", tr, batch=b, rows=s_pad):
+            if uniform:
+                logits, cache = self.executor.prefill(jnp.asarray(tokens),
+                                                      cache)
+            else:
+                logits, cache = self.executor.prefill(
+                    jnp.asarray(tokens), cache, lengths=jnp.asarray(lengths)
+                )
         self.stats["prefill_tokens"] += int(lengths.sum())
         if tracks is not None:
             # the wave decodes in lockstep: per-request decode phases open
@@ -972,13 +987,15 @@ class ServingEngine:
         active = np.ones(b, bool)
         for step in range(budget):
             next_tok = self._sample(logits)
-            next_np = np.asarray(next_tok)
-            for i, r in enumerate(live):
-                if not active[i]:
-                    continue
-                if self._emit(r, int(next_np[i]), int(limits[i])):
-                    active[i] = False
-                    self._retire_obs(r)
+            with span("engine.sync", tr):
+                next_np = np.asarray(next_tok)
+            with span("engine.emit", tr):
+                for i, r in enumerate(live):
+                    if not active[i]:
+                        continue
+                    if self._emit(r, int(next_np[i]), int(limits[i])):
+                        active[i] = False
+                        self._retire_obs(r)
             if not active.any():
                 break
             if uniform:
@@ -989,11 +1006,9 @@ class ServingEngine:
                 index = jnp.asarray(
                     np.minimum(lengths + step, self.max_len - 1), jnp.int32
                 )
-            if tr is not None:
-                tr.begin("engine", "decode_step", live=int(active.sum()))
-            logits, cache = self.executor.decode(next_tok[:, None], cache, index)
-            if tr is not None:
-                tr.end("engine")
+            with span("engine.decode.dispatch", tr, live=int(active.sum())):
+                logits, cache = self.executor.decode(next_tok[:, None], cache,
+                                                     index)
             self.stats["decode_steps"] += 1
             self.stats["decode_tokens"] += int(active.sum())
         for i, r in enumerate(live):

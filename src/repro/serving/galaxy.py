@@ -29,6 +29,11 @@ now only buckets prompt lengths to bound the number of compiled prefill
 shapes.  K/V are written at absolute positions, and each decode step
 overwrites its own cache slot/page entry before attending, so bucket
 padding rows are never read.
+
+Every call into a compiled program runs inside a ``galaxy.prefill`` or
+``galaxy.decode`` profiler span (``repro.obs.trace.span``): the executor's
+own host time, pytree flattening of the weights and dispatch, on the device
+trace's clock.
 """
 from __future__ import annotations
 
@@ -40,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import hmp
 from repro.core.execplan import ExecPlan
+from repro.obs.trace import span
 
 
 class GalaxyHMPExecutor:
@@ -93,27 +99,6 @@ class GalaxyHMPExecutor:
         any length correct; bucketing only bounds compiled prefill shapes."""
         return self.plan.seq_grain
 
-    # --- observability --------------------------------------------------------
-    def wire_stats(self, seq: Optional[int] = None) -> Dict[str, float]:
-        """Ring-transport gauges for the engine's metrics registry.
-
-        Prices one full ring rotation of this plan's :class:`RingSchedule`
-        at ``seq`` rows (default: one bucketing grain, the smallest shape
-        serving ever ships): rows and activation bytes on the wire, and the
-        shipped fraction of what padded transport would move.  Static per
-        plan — the engine snapshots it once per run."""
-        seq = self.plan.seq_grain if seq is None else seq
-        rs = self.plan.ring_schedule(seq)
-        row_bytes = self.plan.d_model * jnp.dtype(self.embed.dtype).itemsize
-        rows = rs.total_wire_rows()
-        return {
-            "ring_wire_seq": float(seq),
-            "ring_wire_rows": float(rows),
-            "ring_wire_rows_padded": float(rs.padded_wire_rows()),
-            "ring_wire_bytes": float(rows * row_bytes),
-            "ring_wire_fraction": float(rs.wire_fraction()),
-        }
-
     # --- wave protocol --------------------------------------------------------
     def make_cache(self, batch: int, max_len: int) -> List[Dict]:
         # cache rows are *absolute* positions (ragged prefill gathers valid
@@ -155,11 +140,13 @@ class GalaxyHMPExecutor:
                 return logits, cache
 
             self._prefill_fns[key] = jax.jit(prefill)
-        if lengths is None:
-            return self._prefill_fns[key](self.layers, self.embed, tokens, cache)
-        return self._prefill_fns[key](
-            self.layers, self.embed, tokens, cache, lengths
-        )
+        with span("galaxy.prefill"):
+            if lengths is None:
+                return self._prefill_fns[key](self.layers, self.embed, tokens,
+                                              cache)
+            return self._prefill_fns[key](
+                self.layers, self.embed, tokens, cache, lengths
+            )
 
     def decode(self, tokens, cache, index):
         if self._decode_fn is None:
@@ -172,7 +159,9 @@ class GalaxyHMPExecutor:
                 return logits, cache
 
             self._decode_fn = jax.jit(decode)
-        return self._decode_fn(self.layers, self.embed, tokens, cache, index)
+        with span("galaxy.decode"):
+            return self._decode_fn(self.layers, self.embed, tokens, cache,
+                                   index)
 
     # --- paged protocol -------------------------------------------------------
     @property
@@ -216,10 +205,11 @@ class GalaxyHMPExecutor:
                 return logits, pool
 
             self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(3,))
-        return self._prefill_fns[key](
-            self.layers, self.embed, tokens, pool, block_row,
-            jnp.asarray(offset, jnp.int32), jnp.asarray(length, jnp.int32),
-        )
+        with span("galaxy.prefill"):
+            return self._prefill_fns[key](
+                self.layers, self.embed, tokens, pool, block_row,
+                jnp.asarray(offset, jnp.int32), jnp.asarray(length, jnp.int32),
+            )
 
     def prefill_paged(self, tokens, pool, block_row, length: int):
         """Prefill one request (batch 1, tokens bucket-padded by the engine)
@@ -245,10 +235,11 @@ class GalaxyHMPExecutor:
 
             # donate the pool so the page scatter happens in place
             self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(3,))
-        return self._prefill_fns[key](
-            self.layers, self.embed, tokens, pool, block_row,
-            jnp.asarray(length, jnp.int32),
-        )
+        with span("galaxy.prefill"):
+            return self._prefill_fns[key](
+                self.layers, self.embed, tokens, pool, block_row,
+                jnp.asarray(length, jnp.int32),
+            )
 
     def decode_paged(self, tokens, pool, block_table, positions):
         if self._decode_paged_fn is None:
@@ -264,6 +255,7 @@ class GalaxyHMPExecutor:
                 return logits, pool
 
             self._decode_paged_fn = jax.jit(decode, donate_argnums=(3,))
-        return self._decode_paged_fn(
-            self.layers, self.embed, tokens, pool, block_table, positions
-        )
+        with span("galaxy.decode"):
+            return self._decode_paged_fn(
+                self.layers, self.embed, tokens, pool, block_table, positions
+            )

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.costmodel import DeviceSpec
+from repro.obs.trace import span
 from repro.serving.kvpool import PagedKVPool
 
 
@@ -164,7 +165,9 @@ class SpeculativeDecoder:
                 jnp.asarray(tok), self.storage, jnp.asarray(bt),
                 jnp.asarray(np.where(mask, pos, 0)),
             )
-            nxt = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+            greedy = jnp.argmax(logits, axis=-1)
+            with span("engine.sync"):
+                nxt = np.asarray(greedy).astype(np.int32)
             for i in active:
                 if j >= len(catchup[i]):  # a proposal, not a catch-up step
                     drafts[i].append(int(nxt[i]))
@@ -201,73 +204,68 @@ def run_spec_round(engine, spec: SpeculativeDecoder, slots, live,
                                      len(sl.req.prompt))
         last[i] = sl.last_token
         posns[i] = sl.next_index
-    if tr is not None:
-        tr.begin("engine", "spec_round", live=len(live))
-        tr.begin("engine", "draft_propose")
-    drafts = spec.propose(live, last, posns, k_eff, catchup)
-    if tr is not None:
-        tr.end("engine")
+    with span("engine.spec_round", tr, live=len(live)):
+        with span("engine.spec_propose", tr):
+            drafts = spec.propose(live, last, posns, k_eff, catchup)
 
-    finished = []
-    for i in live:
-        sl = slots[i]
-        ke = k_eff[i]
-        chunk = np.zeros((1, ke + 1), np.int32)
-        chunk[0, 0] = sl.last_token
-        chunk[0, 1:] = drafts[i][:ke]
-        pool.ensure(i, sl.next_index + ke)
-        block_row = jnp.asarray(pool.block_table[i])
-        if tr is not None:
-            tr.begin("engine", "spec_verify", uid=sl.req.uid, k=ke)
-        t0 = time.perf_counter() if drift is not None else 0.0
-        logits, storage = ex.prefill_chunk(
-            jnp.asarray(chunk), storage, block_row,
-            offset=sl.next_index, length=sl.next_index + ke + 1,
-        )
-        toks = np.asarray(engine._sample_positions(logits))[0]  # (ke+1,)
-        accepted = longest_accepted_prefix(drafts[i][:ke], toks[:ke])
-        if drift is not None:
-            # per-position sampling synced the chunk: wall time for free
-            drift.observe("spec_verify", time.perf_counter() - t0,
-                          rows=ke + 1, context=sl.next_index + ke + 1)
-        if tr is not None:
-            tr.end("engine", accepted=accepted)
+        finished = []
+        for i in live:
+            sl = slots[i]
+            ke = k_eff[i]
+            chunk = np.zeros((1, ke + 1), np.int32)
+            chunk[0, 0] = sl.last_token
+            chunk[0, 1:] = drafts[i][:ke]
+            pool.ensure(i, sl.next_index + ke)
+            with span("engine.spec_verify", tr, uid=sl.req.uid, k=ke) as sp:
+                block_row = jnp.asarray(pool.block_table[i])
+                t0 = time.perf_counter() if drift is not None else 0.0
+                logits, storage = ex.prefill_chunk(
+                    jnp.asarray(chunk), storage, block_row,
+                    offset=sl.next_index, length=sl.next_index + ke + 1,
+                )
+                sampled = engine._sample_positions(logits)
+                with span("engine.sync", tr):
+                    toks = np.asarray(sampled)[0]  # (ke+1,)
+                accepted = longest_accepted_prefix(drafts[i][:ke], toks[:ke])
+                if drift is not None:
+                    # per-position sampling synced the chunk: wall time for free
+                    drift.observe("spec_verify", time.perf_counter() - t0,
+                                  rows=ke + 1, context=sl.next_index + ke + 1)
+                sp.set_metadata(accepted=accepted)
 
-        emitted, done = 0, False
-        for j in range(accepted):
-            emitted += 1
-            if engine._emit(sl.req, int(drafts[i][j]), sl.limit):
-                done = True
-                break
-        if not done:
-            emitted += 1
-            done = engine._emit(sl.req, int(toks[accepted]), sl.limit)
+            emitted, done = 0, False
+            for j in range(accepted):
+                emitted += 1
+                if engine._emit(sl.req, int(drafts[i][j]), sl.limit):
+                    done = True
+                    break
+            if not done:
+                emitted += 1
+                done = engine._emit(sl.req, int(toks[accepted]), sl.limit)
 
-        st = engine.stats
-        st["spec_steps"] += 1
-        st["spec_proposed"] += ke
-        st["spec_accepted"] += accepted
-        # stats["spec_accept_counts"] reads this histogram back as a
-        # value-count dict (the facade returns a copy, so observing the
-        # histogram is the one write path)
-        engine.metrics.histogram("spec_accepted_per_round").observe(accepted)
-        st["decode_steps"] += 1
-        st["decode_tokens"] += emitted
+            st = engine.stats
+            st["spec_steps"] += 1
+            st["spec_proposed"] += ke
+            st["spec_accepted"] += accepted
+            # stats["spec_accept_counts"] reads this histogram back as a
+            # value-count dict (the facade returns a copy, so observing the
+            # histogram is the one write path)
+            engine.metrics.histogram("spec_accepted_per_round").observe(accepted)
+            st["decode_steps"] += 1
+            st["decode_tokens"] += emitted
 
-        new_next = sl.next_index + emitted
-        if done:
-            pool.retire(i)
-            spec.retire(i)
-            finished.append((i, sl.req))
-        else:
-            sl.last_token = int(toks[accepted])
-            sl.next_index = new_next
-            if accepted < ke:
-                if tracks is not None:
-                    tracks.event(sl.req.uid, "spec_rollback",
-                                 rejected=ke - accepted)
-                pool.truncate(i, new_next)
-            spec.observe(i, new_next)
-    if tr is not None:
-        tr.end("engine")  # spec_round
-    return storage, finished
+            new_next = sl.next_index + emitted
+            if done:
+                pool.retire(i)
+                spec.retire(i)
+                finished.append((i, sl.req))
+            else:
+                sl.last_token = int(toks[accepted])
+                sl.next_index = new_next
+                if accepted < ke:
+                    if tracks is not None:
+                        tracks.event(sl.req.uid, "spec_rollback",
+                                     rejected=ke - accepted)
+                    pool.truncate(i, new_next)
+                spec.observe(i, new_next)
+        return storage, finished

@@ -10,7 +10,10 @@ The acceptance bars from the engine side:
   subprocess);
 * greedy tokens are bitwise identical with telemetry on or off;
 * with telemetry disabled the engine executes ZERO tracer / histogram
-  calls per token (structural gate — call counting, not wall clock);
+  calls per token and a bounded number of profiler annotations per step,
+  none per token (structural gate — call counting, not wall clock);
+* the engine's profiler spans nest in their ``engine.step`` and arrive on
+  a profiler capture's host plane with their stats;
 * stats no longer silently persist across ``run()`` calls on a reused
   engine: ``reset_stats()`` zeroes the run scope, lifetime survives.
 """
@@ -360,7 +363,7 @@ def test_traced_serve_zoo_acceptance(zoo):
         assert e["dur"] >= 0 and e["ts"] >= 0
     kinds = {e["name"] for e in spans}
     assert {"queued", "decode"} <= kinds
-    assert "prefill_chunk" in kinds or "wave_prefill" in kinds
+    assert "engine.prefill" in kinds
 
     snap = eng.metrics.snapshot()
     assert snap["histograms"]["ttft_s"]["n"] == 5
@@ -393,16 +396,152 @@ def test_traced_serve_wave_scheduler(zoo):
     assert tracer.open_spans() == []
     _, spans, cov = _span_coverage(tracer, [r for r in done if r.output])
     assert min(cov.values()) >= 0.95
-    assert "wave_prefill" in {e["name"] for e in spans}
+    assert "engine.prefill" in {e["name"] for e in spans}
     rejected = [e for e in spans if e["args"].get("rejected")]
     assert len(rejected) == 1 and rejected[0]["name"] == "queued"
+
+
+class _Annotations:
+    """Stands in for the profiler annotations ``obs.trace.span`` enters:
+    records each span with its arguments and the names of the spans open
+    around it."""
+
+    def __init__(self, monkeypatch):
+        self.spans, self._open = [], []
+        rec = self
+
+        class Annotation:
+            def __init__(self, name, **args):
+                self.name, self.args = name, dict(args)
+
+            def __enter__(self):
+                self.parents = tuple(rec._open)
+                rec._open.append(self.name)
+                rec.spans.append(self)
+                return self
+
+            def __exit__(self, *exc):
+                assert rec._open.pop() == self.name
+
+            def set_metadata(self, **args):
+                self.args.update(args)
+
+        monkeypatch.setattr(obs_trace, "TraceAnnotation", Annotation)
+        monkeypatch.setattr(obs_trace, "StepTraceAnnotation", Annotation)
+
+    def named(self, name):
+        return [a for a in self.spans if a.name == name]
+
+
+class _Calls:
+    """The executor, counting its model calls."""
+
+    def __init__(self, inner):
+        self.inner, self.n = inner, 0
+
+    def __getattr__(self, name):
+        attr = getattr(self.inner, name)
+        if name in ("prefill_paged", "prefill_chunk", "decode_paged"):
+            def call(*a, **k):
+                self.n += 1
+                return attr(*a, **k)
+            return call
+        return attr
+
+
+def _step_children(ann):
+    """Spans under each engine.step, by step, and the spans under none."""
+    steps, outside = [], []
+    for a in ann.spans:
+        if a.name == "engine.step":
+            steps.append([])
+        elif "engine.step" in a.parents:
+            steps[-1].append(a)
+        else:
+            outside.append(a)
+    return steps, outside
+
+
+def test_engine_spans_nest_in_steps_one_sync_per_model_call(zoo, monkeypatch):
+    """One engine.step per loop iteration, every other span inside a step
+    but the run's first admission, one engine.sync per model call, and a
+    fixed number of spans per step whatever the number of live slots."""
+    ann = _Annotations(monkeypatch)
+    calls = _Calls(zoo)
+    eng = _engine(calls)
+    for r in _requests():
+        eng.submit(r)
+    done = eng.run()
+    steps, outside = _step_children(ann)
+    # no chunking: every iteration decodes the slots its admission filled
+    assert len(steps) == eng.stats["decode_steps"] > 0
+    assert [a.args["step_num"] for a in ann.named("engine.step")] == list(
+        range(len(steps)))
+    assert len(ann.named("engine.sync")) == calls.n
+    assert len(ann.named("engine.sample")) == calls.n
+    # the run's first admission precedes the loop
+    assert outside[0].name == "engine.admit"
+    assert all("engine.admit" in a.parents for a in outside[1:])
+    for kids in steps:
+        admitted = sum(a.args["admitted"] for a in kids
+                       if a.name == "engine.admit")
+        names = sorted(a.name for a in kids)
+        # decode: prepare, dispatch, sample, sync, emit; one admission pass;
+        # prefill, sample, sync per request admitted; none per token
+        assert len(names) == 6 + 3 * admitted, names
+        for name in ("engine.decode.prepare", "engine.decode.dispatch",
+                     "engine.emit", "engine.admit"):
+            assert names.count(name) == 1
+        assert names.count("engine.prefill") == admitted
+    assert sum(len(r.output) for r in done) > len(steps)  # tokens > steps
+
+    # chunked prefill and the prefix cache: still nothing outside a step
+    # but the first admission pass
+    ann.spans.clear()
+    eng = _engine(zoo, prefix_cache=True, prefill_chunk=4)
+    for r in _requests():
+        eng.submit(r)
+    eng.run()
+    steps, outside = _step_children(ann)
+    assert outside[0].name == "engine.admit"
+    assert all("engine.admit" in a.parents for a in outside[1:])
+    assert ann.named("engine.prefix_lookup")
+    assert sum(k.name == "engine.prefill" for kids in steps for k in kids) > 0
+    # a mid-prompt chunk is not synced: one sync per prompt and decode step
+    assert len(ann.named("engine.sync")) == 5 + eng.stats["decode_steps"]
+
+
+def test_engine_spans_reach_a_profiler_capture(zoo, tmp_path):
+    """A short CPU capture: the spans land on the host plane with their
+    stats, as the benchmark's reader of them parses them."""
+    sys.path.insert(0, REPO)
+    from perfbench import progspans
+    from repro.serving import Request
+
+    eng = _engine(zoo)
+    eng.submit(Request(uid=0, prompt=[3, 4, 5], max_new_tokens=3))
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("perfbench.window"):
+            eng.run()
+    ps = progspans.load(str(tmp_path))
+    lo, hi = ps.window
+    steps = ps.named("engine.step", lo, hi)
+    assert [s.stats["step_num"] for s in steps] == [0, 1]
+    for s in steps:
+        assert len(ps.inside(s, "engine.decode.dispatch")) == 1
+        assert len(ps.inside(s, "engine.sync")) == 1
+    admit = ps.named("engine.admit", lo, hi)[0]
+    assert admit.stats["admitted"] == 1 and admit.stats["queue"] == 0
+    assert ps.named("engine.decode.dispatch", lo, hi)[0].stats["live"] == 1
 
 
 def test_disabled_telemetry_is_structurally_free(zoo, monkeypatch):
     """Tier-1 overhead gate: with no tracer and no record_times, serving a
     full mix executes ZERO tracer calls and ZERO histogram observations —
-    counted at the class level, not timed."""
+    counted at the class level, not timed — and a bounded number of
+    profiler annotations per engine step."""
     calls = []
+    ann = _Annotations(monkeypatch)
 
     def counting(cls, name):
         orig = getattr(cls, name)
@@ -423,6 +562,10 @@ def test_disabled_telemetry_is_structurally_free(zoo, monkeypatch):
     assert sum(len(r.output) for r in done) > 0
     assert calls == []
     assert eng._trace is None and eng._tracks is None
+    # per step at most 6 spans of its own and 3 per request it admits
+    steps = len(ann.named("engine.step"))
+    assert steps > 0
+    assert len(ann.spans) <= 7 * steps + 3 * len(done) + 1
 
     # a *disabled* tracer is treated exactly like no tracer
     eng2 = _engine(zoo, tracer=Tracer(enabled=False))
@@ -505,8 +648,7 @@ def test_drift_monitor_engine_integration(zoo):
 def test_traced_serve_galaxy_acceptance():
     """The same acceptance bar through the Galaxy HMP executor: an uneven
     3:2:2:1 plan on 4 forced CPU devices, traced end to end — >= 95 % span
-    coverage, ring wire gauges from the plan's RingSchedule, tokens
-    bitwise-unchanged by telemetry."""
+    coverage, tokens bitwise-unchanged by telemetry."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["JAX_PLATFORMS"] = "cpu"  # a CPU emulation never competes for a chip
@@ -557,10 +699,6 @@ def test_traced_serve_galaxy_acceptance():
     snap = eng.metrics.snapshot()
     assert snap['histograms']['ttft_s']['n'] == 4
     assert snap['gauges']['kv_pages_peak'] > 0
-    # ring transport gauges come from the plan's own RingSchedule
-    ws = executor.wire_stats()
-    assert snap['gauges']['ring_wire_rows'] == ws['ring_wire_rows'] > 0
-    assert 0 < snap['gauges']['ring_wire_fraction'] <= 1
 
     _, done_off = run(None)
     assert ({r.uid: tuple(r.output) for r in done}
