@@ -629,10 +629,13 @@ def _decode_dense(layers: Sequence[Dict], x, mesh: Mesh, cache: List[Dict],
 
 # --- paged serving path: pool pages + block tables ----------------------------
 
-# pool pages are (num_pages, page_size, heads, head_dim), head-sharded like
-# the dense cache (same axis position), so page shards line up with the
-# weight shards under any ExecPlan
-PAGED_CACHE_SPEC = CACHE_SPEC
+# pool pages are (num_pages, page_size, heads * head_dim): one row of lanes
+# per position, heads-major, so it is stored unpadded and the programs'
+# per-position scatter and page gather run in the layout it is stored in.
+# The lane axis is sharded: under the plan's padded head layout each
+# device's contiguous block of lanes is exactly its head shard, so page
+# shards line up with the weight shards under any ExecPlan
+PAGED_CACHE_SPEC = P(None, None, AXIS)
 
 
 def make_paged_kv_cache(num_pages: int, page_size: int, num_layers: int,
@@ -641,18 +644,29 @@ def make_paged_kv_cache(num_pages: int, page_size: int, num_layers: int,
     """Head-sharded paged KV pool storage for a stack of HMP layers.
 
     Each layer holds k/v pages of global shape (num_pages, page_size,
-    padded_heads, hd); the head axis carries the plan's padded layout exactly
-    like ``make_kv_cache``, so a slot's gathered pages are shard-compatible
-    with the dense cache.  Page 0 is the null page (``serving/kvpool.py``):
-    idle-slot writes land there and masked reads never see it.
+    padded_heads * hd); the lane axis is the plan's padded head layout
+    flattened heads-major, so a device's lane shard is its head shard and a
+    slot's gathered pages reshape to ``(T, h_loc, hd)`` for attention.  The
+    programs that write and read the pool use this shape directly: no step
+    relayouts a whole layer's pool.  Page 0 is the null page
+    (``serving/kvpool.py``): idle-slot writes land there and masked reads
+    never see it.
     """
-    shape = (num_pages, page_size, plan.padded_heads, plan.head_dim)
+    shape = (num_pages, page_size, plan.padded_heads * plan.head_dim)
     sharding = NamedSharding(mesh, PAGED_CACHE_SPEC)
     return [
         {"k": jax.device_put(jnp.zeros(shape, dtype), sharding),
          "v": jax.device_put(jnp.zeros(shape, dtype), sharding)}
         for _ in range(num_layers)
     ]
+
+
+def _paged_write(pk, pv, phys, within, k, v):
+    """Scatter K/V rows (N, h_loc, hd) into pool rows ``(phys, within)``,
+    each flattened to the pool's ``h_loc * hd`` lanes."""
+    n = k.shape[0]
+    return (pk.at[phys, within].set(k.reshape(n, -1)),
+            pv.at[phys, within].set(v.reshape(n, -1)))
 
 
 def _prefill_paged_layer_local(p, x_loc, pk, pv, phys, within, *, overlap,
@@ -667,8 +681,7 @@ def _prefill_paged_layer_local(p, x_loc, pk, pv, phys, within, *, overlap,
                                    layout=layout, plan=plan, backend=backend)
     if layout is not None:
         k, v = k[:, layout.rows], v[:, layout.rows]
-    pk = pk.at[phys, within].set(k[0])
-    pv = pv.at[phys, within].set(v[0])
+    pk, pv = _paged_write(pk, pv, phys, within, k[0], v[0])
     return y_loc, pk, pv
 
 
@@ -693,8 +706,7 @@ def _prefill_chunk_layer_local(p, x_loc, pk, pv, phys, within, block_row,
                                    ctx=(ctx_k, ctx_v, offset))
     if layout is not None:
         k, v = k[:, layout.rows], v[:, layout.rows]
-    pk = pk.at[phys, within].set(k[0])
-    pv = pv.at[phys, within].set(v[0])
+    pk, pv = _paged_write(pk, pv, phys, within, k[0], v[0])
     return y_loc, pk, pv
 
 
@@ -752,23 +764,21 @@ def _prefill_paged(layers: Sequence[Dict], x, mesh: Mesh,
 
 
 def _paged_kv_gather(pool, block_table, head_ok):
-    """Block-table gather reading only the valid head slots of real pages.
+    """Block-table gather whose pad head lanes read the null page.
 
-    pool: (P, page, H, hd); block_table: (S, W); head_ok: (H,) bool — which
-    padded head slots hold this device's real heads.  Pad head slots' page
-    reads are routed to the null page (page 0): its pad-head entries are
-    zero forever (initialized zero; idle-slot writes put the projection of
-    zero weights there), exactly what the old whole-page gather read out of
-    real pages' pad slots — so the result is bitwise-identical while the
-    gather only touches ``plan.heads[d]`` valid slots of live pages.
-    Returns (S, W*page, H, hd)."""
+    pool: (P, page, H*hd); block_table: (S, W); head_ok: (H,) bool — which
+    padded head slots hold this device's real heads.  A valid head's lanes
+    come from the slot's pages, a pad head's from the null page (page 0),
+    whose pad-head lanes are zero forever (initialized zero; idle-slot
+    writes put the projection of zero weights there), exactly what the
+    whole-page gather reads out of real pages' pad lanes — so the result is
+    bitwise-identical, and whatever a live page's pad lanes hold never
+    reaches attention.  Returns (S, W*page, H, hd)."""
     s, w = block_table.shape
-    page, h, hd = pool.shape[1], pool.shape[2], pool.shape[3]
-    bt = jnp.where(head_ok[None, None, :], block_table[:, :, None], 0)
-    # advanced indices at axes 0 and 2 broadcast to (S, W, H) and land in
-    # front of the kept axes: (S, W, H, page, hd)
-    out = pool[bt, :, jnp.arange(h)[None, None, :], :]
-    return out.transpose(0, 1, 3, 2, 4).reshape(s, w * page, h, hd)
+    h = head_ok.shape[0]
+    lane_ok = jnp.repeat(head_ok, pool.shape[2] // h)
+    out = jnp.where(lane_ok, pool[block_table], pool[0])
+    return out.reshape(s, w * pool.shape[1], h, -1)
 
 
 def _decode_paged_layer_local(p, x, pk, pv, block_table, positions, *,
@@ -778,8 +788,9 @@ def _decode_paged_layer_local(p, x, pk, pv, block_table, positions, *,
     slot batch; block_table: (S, W) physical page per (slot, logical page);
     positions: (S,) absolute position each slot writes this step.
 
-    Scatters the new K/V entry into its page, then gathers each slot's pages
-    into a (S, W*page_size, h_loc, hd) view via the block table and attends
+    Scatters the new K/V entry (flattened to the pool's ``h_loc * hd``
+    lanes) into its page, then gathers each slot's pages into a
+    (S, W*page_size, h_loc, hd) view via the block table and attends
     under the per-slot length mask.  Idle slots carry all-null block rows:
     their write lands in the null page and every null read is masked.
     Backend routing mirrors ``_decode_layer_local``: projections shed pad
@@ -795,14 +806,12 @@ def _decode_paged_layer_local(p, x, pk, pv, block_table, positions, *,
     rows = jnp.arange(x.shape[0])
     phys = block_table[rows, positions // page_size]
     within = positions % page_size
-    pk = pk.at[phys, within].set(k_new[:, 0])
-    pv = pv.at[phys, within].set(v_new[:, 0])
+    pk, pv = _paged_write(pk, pv, phys, within, k_new[:, 0], v_new[:, 0])
 
-    # gather this slot's logical context: (S, W, page, h, hd) -> (S, T, h, hd)
+    # gather this slot's logical context: (S, W, page, h*hd) -> (S, T, h, hd)
     if plan is not None and len(set(plan.heads)) > 1:
-        # uneven heads: read only this device's valid head slots of live
-        # pages — pad slots route to the (zero) null page, bitwise-equal to
-        # the whole-page gather.  Even plans keep the plain gather (and its
+        # uneven heads: pad heads' lanes come from the (zero) null page,
+        # bitwise-equal to the whole-page gather.  Even plans keep the plain gather (and its
         # exact XLA graph): every slot is valid there.
         idx = jax.lax.axis_index(AXIS)
         head_ok = jnp.arange(h_loc) < jnp.asarray(plan.heads, jnp.int32)[idx]

@@ -9,10 +9,15 @@ step against the head-sharded KV cache — both under the same uneven
 Both scheduler protocols are implemented.  Wave: ``make_cache`` /
 ``prefill`` / ``decode`` against a dense per-wave cache.  Paged
 (continuous batching): ``make_pool`` / ``prefill_paged`` / ``decode_paged``
-against a pool of head-sharded KV pages (``hmp.make_paged_kv_cache``) —
-prefill scatters prompt KV straight into this request's pages, decode
-gathers each slot's pages through the block table *inside* the shard_map,
-so every device only ever touches its own head shard of the pool.
+against a pool of head-sharded KV pages (``hmp.make_paged_kv_cache``:
+``(pages, page, heads * head_dim)`` per layer, each position one row of
+lanes, in the layout every paged program writes and reads) — prefill
+scatters prompt KV straight into this request's pages, decode gathers each
+slot's pages through the block table *inside* the shard_map, so every
+device only ever touches its own head shard of the pool.  The paged
+programs are built by ``paged_prefill_program`` / ``chunk_prefill_program``
+/ ``paged_decode_program`` from the mesh and plan alone, so they can be
+lowered for a described chip without weights.
 ``prefill_chunk`` extends the paged protocol for the engine's shared-prefix
 admission flow (lookup -> refcount bump -> suffix-only chunked prefill) and
 chunked prefill: a chunk starts at an arbitrary grain-aligned offset and
@@ -185,26 +190,10 @@ class GalaxyHMPExecutor:
         prompt prefill reads only the last real prompt token's row;
         speculative verification (``serving/spec.py``) compares all rows
         against the draft proposals."""
-        b, s = tokens.shape
-        key = ("chunk", s)
+        key = ("chunk", tokens.shape[1])
         if key not in self._prefill_fns:
-            layout = self.plan.seq_layout(s)
-            mesh, plan, overlap = self.mesh, self.plan, self.overlap
-
-            # offset/length stay traced scalars: one compiled program per
-            # chunk shape, shared by every offset it runs at
-            def prefill(layers, embed, tokens, pool, block_row, offset, length):
-                tokens = layout.scatter(tokens)  # identity when dense
-                x = embed[tokens]  # (1, padded, d)
-                y, pool = hmp.hmp_prefill(
-                    layers, x, mesh, pool, plan=plan, overlap=overlap,
-                    seq=s, block_row=block_row, offset=offset,
-                )
-                y = layout.gather(y)
-                logits = y @ embed.T  # (1, S, V): all chunk rows
-                return logits, pool
-
-            self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(3,))
+            self._prefill_fns[key] = chunk_prefill_program(
+                self.mesh, self.plan, self.overlap, tokens.shape[1])
         with span("galaxy.prefill"):
             return self._prefill_fns[key](
                 self.layers, self.embed, tokens, pool, block_row,
@@ -214,27 +203,10 @@ class GalaxyHMPExecutor:
     def prefill_paged(self, tokens, pool, block_row, length: int):
         """Prefill one request (batch 1, tokens bucket-padded by the engine)
         writing prompt KV straight into this request's pool pages."""
-        b, s = tokens.shape
-        key = ("paged", s)
+        key = ("paged", tokens.shape[1])
         if key not in self._prefill_fns:
-            layout = self.plan.seq_layout(s)
-            mesh, plan, overlap = self.mesh, self.plan, self.overlap
-
-            # length stays a traced scalar so every prompt sharing this
-            # padded shape reuses one compiled program
-            def prefill(layers, embed, tokens, pool, block_row, length):
-                tokens = layout.scatter(tokens)  # identity when dense
-                x = embed[tokens]  # (1, padded, d)
-                y, pool = hmp.hmp_prefill(
-                    layers, x, mesh, pool, plan=plan, overlap=overlap,
-                    seq=s, block_row=block_row,
-                )
-                y = layout.gather(y)
-                logits = y[:, length - 1] @ embed.T
-                return logits, pool
-
-            # donate the pool so the page scatter happens in place
-            self._prefill_fns[key] = jax.jit(prefill, donate_argnums=(3,))
+            self._prefill_fns[key] = paged_prefill_program(
+                self.mesh, self.plan, self.overlap, tokens.shape[1])
         with span("galaxy.prefill"):
             return self._prefill_fns[key](
                 self.layers, self.embed, tokens, pool, block_row,
@@ -243,19 +215,66 @@ class GalaxyHMPExecutor:
 
     def decode_paged(self, tokens, pool, block_table, positions):
         if self._decode_paged_fn is None:
-            mesh, plan = self.mesh, self.plan
-
-            def decode(layers, embed, tokens, pool, block_table, positions):
-                x = embed[tokens]  # (S, 1, d)
-                y, pool = hmp.hmp_decode(
-                    layers, x, mesh, pool, positions, plan=plan,
-                    block_table=block_table,
-                )
-                logits = y[:, -1] @ embed.T
-                return logits, pool
-
-            self._decode_paged_fn = jax.jit(decode, donate_argnums=(3,))
+            self._decode_paged_fn = paged_decode_program(self.mesh, self.plan)
         with span("galaxy.decode"):
             return self._decode_paged_fn(
                 self.layers, self.embed, tokens, pool, block_table, positions
             )
+
+
+# --- paged programs -----------------------------------------------------------
+# Each takes (layers, embed, tokens, pool, ...) and donates the pool, so the
+# page scatter happens in place in the pool's own layout.
+
+def paged_prefill_program(mesh: Mesh, plan: ExecPlan, overlap: bool, s: int):
+    """Jitted prefill of one request (batch 1, ``s`` bucket-padded tokens)
+    writing prompt KV straight into its pool pages; returns the logits of
+    the last real prompt token and the pool."""
+    layout = plan.seq_layout(s)
+
+    # length stays a traced scalar so every prompt sharing this padded shape
+    # reuses one compiled program
+    def prefill(layers, embed, tokens, pool, block_row, length):
+        tokens = layout.scatter(tokens)  # identity when dense
+        x = embed[tokens]  # (1, padded, d)
+        y, pool = hmp.hmp_prefill(layers, x, mesh, pool, plan=plan,
+                                  overlap=overlap, seq=s, block_row=block_row)
+        y = layout.gather(y)
+        logits = y[:, length - 1] @ embed.T
+        return logits, pool
+
+    return jax.jit(prefill, donate_argnums=(3,))
+
+
+def chunk_prefill_program(mesh: Mesh, plan: ExecPlan, overlap: bool, s: int):
+    """Jitted chunked prefill of ``s`` tokens at a traced absolute offset,
+    attending back to the block row's written pages; returns every chunk
+    row's logits (1, S, V) and the pool."""
+    layout = plan.seq_layout(s)
+
+    # offset/length stay traced scalars: one compiled program per chunk
+    # shape, shared by every offset it runs at
+    def prefill(layers, embed, tokens, pool, block_row, offset, length):
+        tokens = layout.scatter(tokens)  # identity when dense
+        x = embed[tokens]  # (1, padded, d)
+        y, pool = hmp.hmp_prefill(layers, x, mesh, pool, plan=plan,
+                                  overlap=overlap, seq=s, block_row=block_row,
+                                  offset=offset)
+        y = layout.gather(y)
+        logits = y @ embed.T  # (1, S, V): all chunk rows
+        return logits, pool
+
+    return jax.jit(prefill, donate_argnums=(3,))
+
+
+def paged_decode_program(mesh: Mesh, plan: ExecPlan):
+    """Jitted slot-batch decode step: (S, 1) tokens, (S, W) block table,
+    (S,) positions; returns (S, V) logits and the pool."""
+    def decode(layers, embed, tokens, pool, block_table, positions):
+        x = embed[tokens]  # (S, 1, d)
+        y, pool = hmp.hmp_decode(layers, x, mesh, pool, positions, plan=plan,
+                                 block_table=block_table)
+        logits = y[:, -1] @ embed.T
+        return logits, pool
+
+    return jax.jit(decode, donate_argnums=(3,))

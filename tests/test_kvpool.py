@@ -1,11 +1,17 @@
-"""PagedKVPool: allocation invariants (incl. hypothesis property test) and
-paged-decode == dense-decode token equality on the default executor."""
+"""PagedKVPool: allocation invariants (incl. hypothesis property test),
+paged-decode == dense-decode token equality on the default executor, and
+the Galaxy executor's pool storage."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import hmp
+from repro.core.execplan import ExecPlan
+from repro.launch.mesh import make_mesh_compat
 from repro.models import init_params
-from repro.serving import PagedKVPool, PoolExhausted, Request, ServingEngine
+from repro.serving import (GalaxyHMPExecutor, PagedKVPool, PoolExhausted,
+                           Request, ServingEngine)
 from repro.serving.kvpool import NULL_PAGE
 
 from helpers import smoke_cfg
@@ -154,3 +160,35 @@ def test_pool_too_small_for_one_request_raises():
     eng.submit(Request(uid=0, prompt=list(range(1, 17)), max_new_tokens=8))
     with pytest.raises(RuntimeError, match="cannot fit"):
         eng.run()
+
+
+def test_galaxy_pool_rows_hold_each_position_kv_flattened():
+    """The Galaxy pool stores each position as one row of ``heads * hd``
+    lanes: after ``prefill_paged`` and one ``decode_paged``, the row at
+    (physical page, in-page slot) of a prompt position and of the decoded
+    position equals that layer's K and V projections there, flattened."""
+    d, h, hd, ff, page = 32, 4, 8, 64, 8
+    plan = ExecPlan.even(1, num_heads=h, d_ff=ff, head_dim=hd, d_model=d)
+    layers = hmp.init_stack_params(jax.random.PRNGKey(0), 2, d, h, ff)
+    emb = jax.random.normal(jax.random.PRNGKey(1), (64, d)) * 0.5
+    ex = GalaxyHMPExecutor(layers, emb, plan, make_mesh_compat((1,), ("model",)))
+    pool = ex.make_pool(num_pages=5, page_size=page)
+    assert pool[0]["k"].shape == (5, page, h * hd)
+
+    tokens = np.arange(3, 14)  # a 10-token prompt, then one decoded token
+    row = np.asarray([3, 1], np.int32)  # logical page 0 -> 3, 1 -> 1
+    _, pool = ex.prefill_paged(jnp.asarray(tokens[None, :10]), pool,
+                               jnp.asarray(row), length=10)
+    _, pool = ex.decode_paged(jnp.asarray(tokens[None, 10:]), pool,
+                              jnp.asarray(row[None]), jnp.asarray([10]))
+
+    x = emb[tokens][None]  # each layer's input over all 11 positions
+    for p, cache in zip(layers, pool):
+        for pos in (4, 10):
+            phys, within = row[pos // page], pos % page
+            for name, w in (("k", p["wk"]), ("v", p["wv"])):
+                want = np.einsum("d,dhk->hk", x[0, pos], w).reshape(-1)
+                np.testing.assert_allclose(
+                    np.asarray(cache[name][phys, within]), want,
+                    rtol=1e-5, atol=1e-5)
+        x = hmp.reference_layer(p, x)

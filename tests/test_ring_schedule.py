@@ -95,11 +95,12 @@ def test_legacy_kwargs_removed():
 def test_paged_kv_gather_reads_only_valid_head_slots():
     """The uneven-heads decode gather routes pad head slots to the null
     page: valid head slots must match the full gather bitwise even when the
-    *other* pages' pad slots hold garbage, and pad head slots must read
-    page 0 (zeros in a real pool) instead of arbitrary pages."""
+    *other* pages' pad lanes hold garbage, and pad head slots must read
+    page 0 (zeros in a real pool) instead of arbitrary pages.  The pool
+    stores each position as one row of ``heads * hd`` lanes."""
     rng = np.random.default_rng(0)
     pages, page, h, hd, s, w = 6, 4, 5, 3, 2, 2
-    pool = jnp.asarray(rng.normal(size=(pages, page, h, hd)))  # garbage all over
+    pool = jnp.asarray(rng.normal(size=(pages, page, h * hd)))  # garbage all over
     block_table = jnp.asarray(rng.integers(1, pages, size=(s, w)), jnp.int32)
     head_ok = jnp.asarray([True, True, True, False, False])
 

@@ -15,18 +15,28 @@ blocks do not divide (gpt2-l's K=1280, qwen1.5-0.5b's 704 columns per chip
 on four chips): the masked tail blocks leave the result bitwise equal to
 the padded-XLA oracle (integer-valued operands make every summation order
 exact).
+
+The Galaxy executor's paged programs (decode, paged and chunked prefill)
+are compiled the same way at gpt2-l widths: the compiled program must not
+relayout a whole layer's K/V pool, which a pool stored in another layout
+than its scatter and gather use costs every step.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import hmp
+from repro.core.execplan import ExecPlan
 from repro.kernels import ops
 from repro.kernels.flash_attention import ragged_flash_attention
 from repro.kernels.fused_connective import fused_connective
 from repro.kernels.tiled_gemm import LANE, SUBLANE, aligned_block, tiled_gemm_valid
+from repro.serving import galaxy
 
 
 @pytest.fixture(scope="module")
@@ -137,3 +147,62 @@ def test_valid_gemm_tail_blocks_bitwise(m, k, n, valid_k, valid_n):
                            interpret=True)
     oracle = ops.gemm(x, w, backend="xla", valid_k=valid_k, valid_n=valid_n)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
+
+
+# --- the Galaxy paged pool keeps its layout through every paged program -------
+
+# gpt2-l widths, depth cut to 2 layers: 8 slots x 8 pages of 128 positions
+# (plus the null page), a 256-token prompt
+_GPT2L = dict(num_heads=20, d_ff=5120, head_dim=64, d_model=1280)
+_VOCAB, _LAYERS, _SLOTS, _ROW, _PAGE, _PROMPT = 50304, 2, 8, 8, 128, 256
+
+
+def _paged_program(program, mesh, plan):
+    """The executor's jitted paged program and its argument shapes."""
+    def sharded(tree, spec_of):
+        return jax.tree.map(lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)), tree, spec_of)
+
+    layer = jax.eval_shape(lambda: plan.pad_layer_params(hmp.init_layer_params(
+        jax.random.PRNGKey(0), plan.d_model, plan.num_heads, plan.d_ff)))
+    layer = sharded(layer, {k: hmp.layer_param_specs()[k] for k in layer})
+    pool = jax.eval_shape(lambda: hmp.make_paged_kv_cache(
+        1 + _SLOTS * _ROW, _PAGE, _LAYERS, mesh, plan))
+    pool = sharded(pool, jax.tree.map(lambda _: hmp.PAGED_CACHE_SPEC, pool))
+    rep = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=NamedSharding(mesh, P()))
+    embed = jax.ShapeDtypeStruct((_VOCAB, plan.d_model), jnp.float32,
+                                 sharding=NamedSharding(mesh, P()))
+    head = ([layer] * _LAYERS, embed)
+    if program == "decode":
+        fn = galaxy.paged_decode_program(mesh, plan)
+        args = (*head, rep(_SLOTS, 1), pool, rep(_SLOTS, _ROW), rep(_SLOTS))
+    elif program == "prefill_paged":
+        fn = galaxy.paged_prefill_program(mesh, plan, True, _PROMPT)
+        args = (*head, rep(1, _PROMPT), pool, rep(_ROW), rep())
+    else:
+        fn = galaxy.chunk_prefill_program(mesh, plan, True, _PROMPT)
+        args = (*head, rep(1, _PROMPT), pool, rep(_ROW), rep(), rep())
+    return fn, args, pool
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_paged", "prefill_chunk"])
+def test_galaxy_paged_program_keeps_the_pool_layout(one_chip, program):
+    """Paged decode and paged (and chunked) prefill read and write the K/V
+    pool in the layout it is stored in: no ``copy`` of a whole layer's pool
+    in the compiled program, and every pool parameter leaves in the layout
+    it came in (the scatter runs in place on the donated buffer)."""
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("model",))
+    plan = ExecPlan.even(1, **_GPT2L)
+    fn, args, pool = _paged_program(program, mesh, plan)
+    compiled = fn.lower(*args).compile()
+
+    shape = pool[0]["k"].shape
+    pool_hlo = re.escape("f32[%s]" % ",".join(map(str, shape)))
+    copies = re.findall(rf"= {pool_hlo}\{{[^}}]*\}} copy\(", compiled.as_text())
+    assert not copies, f"{len(copies)} whole-pool copies in {program}"
+
+    (ins, _), outs = compiled.input_formats, compiled.output_formats
+    for layer_in, layer_out in zip(ins[3], outs[1]):
+        for kv in ("k", "v"):
+            assert layer_in[kv].layout == layer_out[kv].layout
