@@ -9,7 +9,9 @@ from __future__ import annotations
 import importlib
 from typing import Dict
 
-from repro.configs.base import ModelConfig, reduced  # noqa: F401
+from repro.configs.base import (  # noqa: F401
+    MLAMoEConfig, ModelConfig, reduced, reduced_mla_moe,
+)
 
 # arch-id -> module under repro.configs
 _ASSIGNED = {
@@ -27,17 +29,28 @@ _ASSIGNED = {
 
 ASSIGNED_ARCHS = tuple(_ASSIGNED)
 
+# arch-id -> module: blocks only the Galaxy executor serves (core/mla_moe.py);
+# their configs are not ModelConfigs and the model zoo does not build them
+_GALAXY_ONLY = {
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+}
 
-def get_config(name: str) -> ModelConfig:
-    if name in _ASSIGNED:
-        mod = importlib.import_module(f"repro.configs.{_ASSIGNED[name]}")
+GALAXY_ARCHS = tuple(_GALAXY_ONLY)
+
+
+def get_config(name: str):
+    """The ``ModelConfig`` of a zoo or paper arch, or the ``MLAMoEConfig``
+    of a Galaxy-only arch."""
+    modules = {**_ASSIGNED, **_GALAXY_ONLY}
+    if name in modules:
+        mod = importlib.import_module(f"repro.configs.{modules[name]}")
         return mod.CONFIG
     from repro.configs.paper_models import PAPER_MODELS
 
     if name in PAPER_MODELS:
         return PAPER_MODELS[name]
     raise KeyError(
-        f"unknown arch {name!r}; known: {sorted(_ASSIGNED) + ['distilbert', 'bert-l', 'gpt2-l', 'opt-l', 'opt-xl']}"
+        f"unknown arch {name!r}; known: {sorted(modules) + ['distilbert', 'bert-l', 'gpt2-l', 'opt-l', 'opt-xl']}"
     )
 
 

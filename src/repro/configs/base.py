@@ -223,3 +223,57 @@ def reduced(cfg: ModelConfig, d_model: int = 256, vocab: int = 512) -> ModelConf
         param_dtype="float32",
         remat=False,
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAMoEConfig:
+    """A DeepSeek-V3 block stack: latent attention (MLA) with no query
+    compression, ``first_dense`` dense SwiGLU layers, then mixture-of-experts
+    layers (sigmoid router over ``num_experts`` with a selection bias,
+    top-``experts_per_token``, normalised and scaled weights, plus shared
+    experts), pre-norm RMSNorm, a final norm and an untied head.
+
+    ``experts_held`` is how many routed experts this executor holds of each
+    layer (the first ones; the whole layer when equal to ``num_experts``):
+    the router still scores all ``num_experts``.  Served through
+    ``GalaxyMLAExecutor`` (``core/mla_moe.py``), not the model zoo."""
+
+    name: str
+    source: str
+    num_layers: int = 27
+    first_dense: int = 1
+    d_model: int = 2048
+    num_heads: int = 16
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    d_ff: int = 11264             # the dense layers' SwiGLU width
+    d_expert: int = 1408          # each routed expert's SwiGLU width
+    num_shared: int = 2           # shared experts, one SwiGLU of their sum
+    num_experts: int = 64         # the router's width
+    experts_per_token: int = 6
+    experts_held: int = 64
+    routed_scale: float = 2.446
+    rope_theta: float = 50000.0
+    eps: float = 1e-5
+    vocab_size: int = 163840
+    max_position: int = 8192
+
+    def __post_init__(self):
+        if not 0 < self.experts_held <= self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} not in "
+                             f"(0, {self.num_experts}]")
+        if not 0 <= self.first_dense <= self.num_layers:
+            raise ValueError("first_dense must lie within num_layers")
+
+
+def reduced_mla_moe(cfg: MLAMoEConfig) -> MLAMoEConfig:
+    """CPU smoke variant of an MLA/MoE stack: 3 layers (one dense), narrow
+    widths, 8 routed experts of which 4 are held, top-2."""
+    return dataclasses.replace(
+        cfg, name=cfg.name + "-smoke", num_layers=3, first_dense=1,
+        d_model=64, num_heads=4, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, kv_lora_rank=32, d_ff=128, d_expert=32, num_shared=1,
+        num_experts=8, experts_per_token=2, experts_held=4, vocab_size=512,
+        max_position=1024)

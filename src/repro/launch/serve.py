@@ -8,6 +8,9 @@ all local devices (an even ExecPlan over the device mesh) instead of the
 GSPMD model zoo; there ``--compute-backend pallas`` switches the per-shard
 compute path to the valid-length Pallas kernels (``ExecPlan.compute_backend``
 — pad-block work is shed per device; "xla" keeps the padded dense oracle).
+It builds the arch's own block: the paper's Fig. 2 layer for the paper's
+models (``--arch gpt2-l``...), the DeepSeek-V3 block on one device for
+``--arch moonlight-16b-a3b``; any other arch is refused.
 
 ``--prefix-cache on`` shares prompt-prefix KV across requests through the
 radix-tree cache (``serving/prefix_cache.py``; requests get a common system
@@ -37,7 +40,11 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import ASSIGNED_ARCHS, get_config, reduced
+from repro.configs import (
+    ASSIGNED_ARCHS, GALAXY_ARCHS, MLAMoEConfig, get_config, reduced,
+    reduced_mla_moe,
+)
+from repro.configs.paper_models import PAPER_MODELS
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import (
@@ -46,12 +53,34 @@ from repro.serving import (
 
 
 def _galaxy_executor(cfg, compute_backend: str):
-    """An even Galaxy HMP executor over every local device."""
-    from repro.core import hmp
+    """A Galaxy HMP executor for the arch's own block, or SystemExit.
+
+    The Galaxy path implements two blocks: the paper's Fig. 2 layer, which
+    the paper's evaluation models are stacks of (an even plan over every
+    local device), and the DeepSeek-V3 block of an ``MLAMoEConfig`` (one
+    device; every routed expert held).  Any other arch is refused rather
+    than served as the paper layer at its widths."""
+    from repro.core import hmp, mla_moe
     from repro.core.execplan import ExecPlan
     from repro.launch.mesh import make_mesh_compat
-    from repro.serving import GalaxyHMPExecutor
+    from repro.serving import GalaxyHMPExecutor, GalaxyMLAExecutor
 
+    if isinstance(cfg, MLAMoEConfig):
+        if compute_backend != "xla":
+            raise SystemExit(f"{cfg.name}: the MLA/MoE block has no "
+                             "'pallas' compute backend")
+        plan = ExecPlan.even(1, num_heads=cfg.num_heads, d_ff=cfg.d_ff,
+                             head_dim=cfg.v_head_dim, d_model=cfg.d_model)
+        mesh = make_mesh_compat((1,), ("model",), devices=jax.devices()[:1])
+        layers, vocab = jax.jit(lambda k: mla_moe.init_params(cfg, k))(
+            jax.random.PRNGKey(0))
+        return GalaxyMLAExecutor(layers, vocab, plan, mesh)
+    if cfg.name.removesuffix("-smoke") not in PAPER_MODELS:
+        raise SystemExit(
+            f"{cfg.name}: the Galaxy executor implements the paper's Fig. 2 "
+            f"layer ({', '.join(PAPER_MODELS)}) and the DeepSeek-V3 block "
+            f"({', '.join(GALAXY_ARCHS)}), not this arch's block; serve it "
+            "with --executor zoo")
     n = jax.device_count()
     if cfg.num_heads % n or cfg.d_ff % n:
         raise SystemExit(
@@ -72,7 +101,11 @@ def _galaxy_executor(cfg, compute_backend: str):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen1.5-0.5b", choices=ASSIGNED_ARCHS)
+    ap.add_argument("--arch", default="qwen1.5-0.5b",
+                    choices=ASSIGNED_ARCHS + GALAXY_ARCHS + tuple(PAPER_MODELS),
+                    help="a zoo arch; the Galaxy-only blocks "
+                         f"({', '.join(GALAXY_ARCHS)}) and the paper's "
+                         "models serve through --executor galaxy")
     ap.add_argument("--reduce", action="store_true")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -133,9 +166,13 @@ def main():
     enable_compile_cache()
 
     cfg = get_config(args.arch)
+    galaxy_only = isinstance(cfg, MLAMoEConfig)
     if args.reduce:
-        cfg = reduced(cfg)
-    if cfg.input_mode != "token":
+        cfg = reduced_mla_moe(cfg) if galaxy_only else reduced(cfg)
+    if galaxy_only and args.executor != "galaxy":
+        raise SystemExit(f"{cfg.name}: its block serves through "
+                         "--executor galaxy only")
+    if not galaxy_only and cfg.input_mode != "token":
         raise SystemExit(f"{cfg.name} is a stub-frontend arch; serve the token archs")
 
     draft_executor = None
@@ -186,6 +223,8 @@ def main():
         from repro.obs import Tracer
         tracer = Tracer()
     drift = None
+    if args.drift and galaxy_only:
+        raise SystemExit("--drift prices the zoo and paper archs only")
     if args.drift:
         from repro.core import costmodel
         from repro.core.execplan import ExecPlan

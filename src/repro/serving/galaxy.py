@@ -39,6 +39,19 @@ Every call into a compiled program runs inside a ``galaxy.prefill`` or
 ``galaxy.decode`` profiler span (``repro.obs.trace.span``): the executor's
 own host time, pytree flattening of the weights and dispatch, on the device
 trace's clock.
+
+``GalaxyMLAExecutor`` serves a second block, the DeepSeek-V3 layer
+(``mla_moe.MLALayer``: latent attention, dense or mixture-of-experts
+feed-forward, with a ``mla_moe.Vocab`` of embedding, final norm and untied
+head), through the paged protocol alone, on a one-device plan, with a
+latent pool (``mla_moe.make_latent_pool``).  Its programs
+(``mla_prefill_program``, ``mla_decode_program``) also return the chosen
+expert ids, kept as ``routing`` after each call, and add each held expert's
+assignments to ``moe_load`` on the device.  Its counters, in ``metrics``:
+``moe.tokens_routed`` and ``mla.latent_positions_read`` per call (host
+arithmetic on shapes), ``moe.assignments_here`` when ``read_moe_load``
+reads the per-expert counts back; its ``galaxy.decode`` spans carry the
+call's number as ``call``.
 """
 from __future__ import annotations
 
@@ -46,10 +59,12 @@ from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import hmp
+from repro.core import hmp, mla_moe
 from repro.core.execplan import ExecPlan
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import span
 
 
@@ -83,19 +98,22 @@ class GalaxyHMPExecutor:
         self.plan = plan
         self.mesh = mesh
         self.overlap = overlap
+        self._place(layers, embed)
+        self._prefill_fns: Dict = {}
+        self._decode_fn = None
+        self._decode_paged_fn = None
+
+    def _place(self, layers, embed) -> None:
         # weights live sharded over the mesh once, as every step reads them
         # (head/column shards per device; the embedding is replicated)
         specs = hmp.layer_param_specs()
         self.layers = [
-            jax.device_put(plan.ensure_padded(p),
-                           {k: NamedSharding(mesh, specs[k]) for k in p})
+            jax.device_put(self.plan.ensure_padded(p),
+                           {k: NamedSharding(self.mesh, specs[k]) for k in p})
             for p in layers
         ]
         self.embed = jax.device_put(jnp.asarray(embed),
-                                    NamedSharding(mesh, P()))
-        self._prefill_fns: Dict = {}
-        self._decode_fn = None
-        self._decode_paged_fn = None
+                                    NamedSharding(self.mesh, P()))
 
     # --- padding policy -------------------------------------------------------
     @property
@@ -222,6 +240,93 @@ class GalaxyHMPExecutor:
             )
 
 
+class GalaxyMLAExecutor(GalaxyHMPExecutor):
+    """The paged protocol over a stack of ``mla_moe.MLALayer`` (the
+    DeepSeek-V3 block) and its ``mla_moe.Vocab``, on a one-device plan.
+
+    Serves through the engine's paged entry points only: a multi-device
+    plan, or the wave protocol's dense cache, raises.  Each call keeps the
+    program's expert ids as ``routing`` and adds its held experts'
+    assignments to ``moe_load`` on the device."""
+
+    def _place(self, layers: Sequence[mla_moe.MLALayer],
+               vocab: mla_moe.Vocab) -> None:
+        if self.plan.num_devices != 1:
+            raise NotImplementedError(
+                f"the MLA/MoE block runs on a one-device plan; this plan has "
+                f"{self.plan.num_devices} devices (no four-chip cell judges "
+                "its head and expert shards yet)")
+        self.layers = [jax.device_put(l, mla_moe.layer_shardings(l, self.mesh))
+                       for l in layers]
+        self.embed = jax.device_put(vocab, NamedSharding(self.mesh, P()))
+        self.moe_load = jnp.zeros((mla_moe.moe_layers(layers),
+                                   mla_moe.experts_held(layers)), jnp.int32)
+        self.routing = None
+        self.metrics = MetricsRegistry()
+        self._calls = 0
+
+    def _count(self, rows: int, read: int) -> None:
+        m = self.metrics
+        m.counter("moe.tokens_routed", "token-layer pairs routed").inc(
+            rows * self.moe_load.shape[0])
+        m.counter("mla.latent_positions_read",
+                  "pool rows read, per layer").inc(read * len(self.layers))
+
+    def read_moe_load(self) -> np.ndarray:
+        """Each held expert's assignments so far, (moe_layers, held): one
+        read from the device; sets ``moe.assignments_here``."""
+        load = np.asarray(self.moe_load)
+        c = self.metrics.counter("moe.assignments_here",
+                                 "token-expert pairs computed on this chip")
+        c.inc(int(load.sum()) - c.value)
+        return load
+
+    def make_cache(self, batch: int, max_len: int) -> List[Dict]:
+        raise NotImplementedError(
+            "the MLA/MoE block serves through the paged protocol only")
+
+    def make_pool(self, num_pages: int, page_size: int) -> List[Dict]:
+        return mla_moe.make_latent_pool(num_pages, page_size, self.layers,
+                                        self.mesh)
+
+    def prefill_chunk(self, tokens, pool, block_row, *, offset, length):
+        """A chunk at absolute positions [offset, offset + S) attending back
+        to the block row; every row's logits, (1, S, V)."""
+        read = block_row.shape[0] * pool[0]["c"].shape[1]
+        return self._prefill(("chunk", tokens.shape[1]), tokens, pool,
+                             block_row, length, offset, read=read)
+
+    def prefill_paged(self, tokens, pool, block_row, length: int):
+        return self._prefill(("paged", tokens.shape[1]), tokens, pool,
+                             block_row, length)
+
+    def _prefill(self, key, tokens, pool, block_row, length, offset=None,
+                 read: int = 0):
+        if key not in self._prefill_fns:
+            self._prefill_fns[key] = mla_prefill_program(
+                self.mesh, tokens.shape[1], chunk=offset is not None)
+        args = () if offset is None else (jnp.asarray(offset, jnp.int32),)
+        with span("galaxy.prefill"):
+            logits, pool, self.routing, self.moe_load = \
+                self._prefill_fns[key](
+                    self.layers, self.embed, tokens, pool, block_row,
+                    jnp.asarray(length, jnp.int32), self.moe_load, *args)
+        self._count(tokens.shape[1], read)
+        return logits, pool
+
+    def decode_paged(self, tokens, pool, block_table, positions):
+        if self._decode_paged_fn is None:
+            self._decode_paged_fn = mla_decode_program(self.mesh)
+        with span("galaxy.decode", call=self._calls):
+            logits, pool, self.routing, self.moe_load = self._decode_paged_fn(
+                self.layers, self.embed, tokens, pool, block_table, positions,
+                self.moe_load)
+        self._calls += 1
+        s, w = block_table.shape
+        self._count(s, s * w * pool[0]["c"].shape[1])
+        return logits, pool
+
+
 # --- paged programs -----------------------------------------------------------
 # Each takes (layers, embed, tokens, pool, ...) and donates the pool, so the
 # page scatter happens in place in the pool's own layout.
@@ -278,3 +383,38 @@ def paged_decode_program(mesh: Mesh, plan: ExecPlan):
         return logits, pool
 
     return jax.jit(decode, donate_argnums=(3,))
+
+
+def mla_prefill_program(mesh: Mesh, s: int, *, chunk: bool = False):
+    """Jitted prefill of one request (``s`` bucket-padded tokens) through an
+    ``MLALayer`` stack into its latent pool pages.  Plain: the logits of the
+    last real prompt token (1, V); ``chunk``: a chunk at a traced absolute
+    offset (last argument) attending back to the block row, every row's
+    logits (1, S, V).  Also returns the chosen expert ids and the per-expert
+    load (donated) plus this call's assignments."""
+    def prefill(layers, vocab, tokens, pool, block_row, length, load,
+                offset=None):
+        x = vocab.table[tokens[0]]  # (S, d)
+        y, pool, ids, sizes = mla_moe.prefill(layers, x, mesh, pool,
+                                              block_row, offset=offset)
+        eps = layers[0].block.eps
+        logits = (mla_moe.head(vocab, y, eps)[None] if chunk
+                  else mla_moe.head(vocab, y[length - 1], eps)[None])
+        return logits, pool, ids, load + sizes
+
+    return jax.jit(prefill, donate_argnums=(3, 6))
+
+
+def mla_decode_program(mesh: Mesh):
+    """Jitted slot-batch decode step of an ``MLALayer`` stack: (S, 1)
+    tokens, (S, W) block table, (S,) positions; returns (S, V) logits, the
+    pool, the chosen expert ids (moe_layers, S, k) and the per-expert load
+    (donated) plus this step's assignments."""
+    def decode(layers, vocab, tokens, pool, block_table, positions, load):
+        x = vocab.table[tokens[:, 0]]  # (S, d)
+        y, pool, ids, sizes = mla_moe.decode(layers, x, mesh, pool,
+                                             block_table, positions)
+        logits = mla_moe.head(vocab, y, layers[0].block.eps)
+        return logits, pool, ids, load + sizes
+
+    return jax.jit(decode, donate_argnums=(3, 6))

@@ -19,7 +19,9 @@ exact).
 The Galaxy executor's paged programs (decode, paged and chunked prefill)
 are compiled the same way at gpt2-l widths: the compiled program must not
 relayout a whole layer's K/V pool, which a pool stored in another layout
-than its scatter and gather use costs every step.
+than its scatter and gather use costs every step.  So are the DeepSeek-V3
+block's decode and paged prefill at moonlight-16b-a3b widths, against its
+latent pool.
 """
 import os
 import re
@@ -206,3 +208,62 @@ def test_galaxy_paged_program_keeps_the_pool_layout(one_chip, program):
     for layer_in, layer_out in zip(ins[3], outs[1]):
         for kv in ("k", "v"):
             assert layer_in[kv].layout == layer_out[kv].layout
+
+
+# --- the DeepSeek-V3 block's paged programs ----------------------------------
+
+# moonlight-16b-a3b widths, depth cut to the dense layer and one MoE layer
+# holding 16 of 64 experts; the cell's 16 slots x 32 pages of 256 positions
+# (plus the null page); a 512-token prompt
+_MLA_SLOTS, _MLA_ROW, _MLA_PAGE, _MLA_PROMPT = 16, 32, 256, 512
+
+
+def _mla_program(program, mesh):
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.core import mla_moe
+
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), num_layers=2,
+                              experts_held=16, vocab_size=40960)
+    layers, vocab = jax.eval_shape(
+        lambda: mla_moe.init_params(cfg, jax.random.PRNGKey(0)))
+    put = lambda a, spec: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, spec))
+    layers = [jax.tree.map(put, l, mla_moe.layer_specs(l)) for l in layers]
+    vocab = jax.tree.map(lambda a: put(a, P()), vocab)
+    lanes = mla_moe.pool_lanes(layers[0])
+    pool = [{"c": jax.ShapeDtypeStruct(
+        (1 + _MLA_SLOTS * _MLA_ROW, _MLA_PAGE, lanes), jnp.float32,
+        sharding=NamedSharding(mesh, P()))} for _ in layers]
+    rep = lambda *shape: put(jax.ShapeDtypeStruct(shape, jnp.int32), P())
+    load = rep(1, cfg.experts_held)
+    if program == "decode":
+        fn = galaxy.mla_decode_program(mesh)
+        args = (layers, vocab, rep(_MLA_SLOTS, 1), pool,
+                rep(_MLA_SLOTS, _MLA_ROW), rep(_MLA_SLOTS), load)
+    else:
+        fn = galaxy.mla_prefill_program(mesh, _MLA_PROMPT)
+        args = (layers, vocab, rep(1, _MLA_PROMPT), pool, rep(_MLA_ROW),
+                rep(), load)
+    return fn, args, pool
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_paged"])
+def test_mla_paged_program_compiles_and_keeps_the_pool_layout(one_chip,
+                                                              program):
+    """The MLA/MoE block's decode and paged prefill compile for a v5e at the
+    model's widths, run the held experts as grouped GEMMs (a ragged dot),
+    and write the latent pool in place: no ``copy`` of a layer's pool, and
+    every pool parameter leaves in the layout it came in."""
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("model",))
+    fn, args, pool = _mla_program(program, mesh)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "ragged" in text
+    pool_hlo = re.escape("f32[%s]" % ",".join(map(str, pool[0]["c"].shape)))
+    copies = re.findall(rf"= {pool_hlo}\{{[^}}]*\}} copy\(", text)
+    assert not copies, f"{len(copies)} whole-pool copies in {program}"
+    (ins, _), outs = compiled.input_formats, compiled.output_formats
+    for layer_in, layer_out in zip(ins[3], outs[1]):
+        assert layer_in["c"].layout == layer_out["c"].layout
